@@ -45,9 +45,10 @@ def run(n=2000, max_mappings=20000):
     _ = np.asarray(out["cycles"])
     batch_us = (time.time() - t0) * 1e6 / n
 
+    from repro.core.backend import default_interpret
     from repro.kernels.mapspace_eval.ops import mapspace_eval
     t0 = time.time()
-    mapspace_eval(ms, block=256, interpret=True)
+    mapspace_eval(ms, block=256, interpret=default_interpret())
     kernel_us = (time.time() - t0) * 1e6 / n
 
     # backend dispatch layer over the same mapspace (jnp vs routed pallas;

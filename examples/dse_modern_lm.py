@@ -182,6 +182,8 @@ if __name__ == "__main__":
                          "mapspaces through the kernels/mapspace_eval "
                          "Pallas kernel; interpret mode off-TPU)")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.strategy:
         run_search_dse(args.strategy, args.budget, args.compare_exhaustive,
                        args.seed, args.backend,

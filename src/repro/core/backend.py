@@ -6,8 +6,8 @@ pair) and routes each mapping to one of two numerically-matched engines:
   * ``jnp``    — `core.batch_eval.evaluate_batch`, the vectorized oracle
     (validated against the scalar evaluator and the loop simulator);
   * ``pallas`` — `kernels.mapspace_eval`, the paper's mapping-scoring hot
-    loop as a Pallas TPU kernel (VPU vector arithmetic over [BLOCK, SLOTS]
-    rows).  On hosts without a TPU the kernel runs under
+    loop as a Pallas TPU kernel (VPU vector arithmetic with the mapping
+    axis on the lanes).  On hosts without a TPU the kernel runs under
     ``pl.pallas_call(..., interpret=True)`` so the code path is always
     testable; on TPU it compiles for the VPU.
 
@@ -94,15 +94,6 @@ def eligibility_mask(mappings) -> np.ndarray:
                        count=len(mappings))
 
 
-def _kernel_block(n: int, block: int) -> int:
-    """Shrink the mapping-axis block for small batches (the ops wrapper
-    pads to a block multiple; a 12-mapping group should not pad to 256)."""
-    b = 8
-    while b < n and b < block:
-        b *= 2
-    return b
-
-
 def validity_mask_arrays(st: HwStatic, factors: np.ndarray,
                          store: np.ndarray) -> np.ndarray:
     """Fanout + buffer-capacity validity over packed arrays,
@@ -152,10 +143,8 @@ def _pallas_scores_arrays(st: HwStatic, factors, rank, goal: str,
     from ..kernels.mapspace_eval.ops import mapspace_eval_arrays
     if interpret is None:
         interpret = default_interpret()
-    n = factors.shape[0]
-    cycles, energy = mapspace_eval_arrays(
-        st, factors, rank, block=_kernel_block(n, block),
-        interpret=interpret)
+    cycles, energy = mapspace_eval_arrays(st, factors, rank, block=block,
+                                          interpret=interpret)
     if goal == "latency":
         return np.asarray(cycles, np.float64)
     if goal == "energy":

@@ -7,8 +7,9 @@ For each hardware description in the architecture space:
   optimal architecture.
 
 Identical workloads (repeated layers) share one mapspace evaluation.
-Evaluation uses the vectorized batch evaluator when available (falls back to
-the scalar path transparently).
+Evaluation uses the vectorized batch evaluator for mapspaces of 64 or more
+mappings (`use_batch=False` selects the scalar path explicitly); a batch
+scorer error propagates.
 """
 from __future__ import annotations
 
@@ -134,18 +135,12 @@ def find_optimal_mapping(workload: Workload, hw: HardwareDesc,
             f"empty valid mapspace for {workload.name} on {hw.name}")
     best_m, best_e, best_v = None, None, math.inf
     if use_batch and len(space.mappings) >= 64:
-        try:
-            from .batch_eval import batch_best_index
-            idx = batch_best_index(space.mappings, goal, backend=backend)
-            best_m = space.mappings[idx]
-            best_e = evaluate_mapping(best_m)
-            best_v = score(best_e)
-        except Exception:
-            if backend != "jnp":
-                raise               # explicit engines fail loudly; only the
-                # seed jnp path degrades to the scalar loop
-            best_m = None
-    if best_m is None:
+        from .batch_eval import batch_best_index
+        idx = batch_best_index(space.mappings, goal, backend=backend)
+        best_m = space.mappings[idx]
+        best_e = evaluate_mapping(best_m)
+        best_v = score(best_e)
+    else:
         for m in space.mappings:
             e = evaluate_mapping(m)
             v = score(e)

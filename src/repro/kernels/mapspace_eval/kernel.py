@@ -2,31 +2,35 @@
 
 A mapspace is a batch of mappings; scoring one mapping is ~1k scalar ops
 (innermost-relevant-loop scans, masked products, traffic/energy sums), so a
-Timeloop-style Python loop is interpreter-bound.  Here a block of mappings
-is laid out as [BLOCK, SLOTS] rows in VMEM and the whole scoring pipeline
-is VPU vector arithmetic (the slot axis padded towards the 128-lane
-register width; chain levels statically unrolled).
+Timeloop-style Python loop is interpreter-bound.  Here the mapping axis is
+the lane axis: every per-mapping tensor is stored feature-major, [F, B],
+a block of mappings is a [F, BLOCK] tile in VMEM, and the whole scoring
+pipeline is VPU vector arithmetic on [S, BLOCK] slot tiles and [1, BLOCK]
+rows (chain levels and tensors statically unrolled).  Slot reductions run
+over sublanes, and the outputs are lane-dense [1, BLOCK] rows.
 
 Semantics: identical to core.batch_eval restricted to no-bypass mappings
 (storage chain = all memory levels) — including input halo credit, psum
 read-modify-write, NoC classification, and zero-skip energy discounts.
-The ops wrapper precomputes per mapping (cheap numpy):
+The ops wrapper precomputes per mapping (cheap numpy), row k of each
+tensor being feature k of every mapping:
 
-  bounds/cum [B,S]     slot loop bounds (nest order) and their cumprod
-  rel_{i,w,o} [B,S]    relevance masks per tensor
-  tw_u/tw_p [B,L1,3]   union / per-instance tile words per chain pair
-  fresh [B,L1,S]       input fresh-words if the innermost relevant slot is
+  bounds/cum [S,B]     slot loop bounds (nest order) and their cumprod
+  rel_{i,w,o} [S,B]    relevance masks per tensor
+  tw_u/tw_p [L1*3,B]   union / per-instance tile words per chain pair
+                       (row j*3+t: pair j, tensor t)
+  fresh [L1,S,B]       input fresh-words if the innermost relevant slot is
                        this slot (== tw_u for non-sliding dims => the
                        sliding formula is uniform)
-  ia/ib [B,L1]         parent/child used-instance counts per pair
-  noc_e [B,L1,3]       NoC pJ/word per pair per tensor (0 if no crossing)
-  noc_m [B,L1]         1 if the pair crosses a routing level
+  ia/ib [L1,B]         parent/child used-instance counts per pair
+  noc_e [L1*3,B]       NoC pJ/word per pair per tensor (0 if no crossing)
+  noc_m [L1,B]         1 if the pair crosses a routing level
 
 The scoring math lives once, in `_score_body`, parameterized by how the
 hardware/workload constants are sourced: the single-arch kernel bakes
 them as static Python floats (functools.partial), the multi-arch variant
-reads them from per-row arrays (zsf [B, L1, 3], mem_par [B, Lm, 3],
-hw_row [B, 4]) so rows of any architectures sharing a structural
+reads them from per-row arrays (zsf [L1*3, B], mem_par [Lm*3, B],
+hw_row [4, B]) so rows of any architectures sharing a structural
 BatchSig fuse into one call — the `evaluate_batch_multi` contract.
 """
 from __future__ import annotations
@@ -39,6 +43,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
+def _row(ref, k: int):
+    """Feature row k of a [F, Bm] ref as a [1, Bm] vector."""
+    return ref[k:k + 1, :]
+
+
 def _score_body(bounds_ref, cum_ref, rel_i_ref, rel_w_ref, rel_o_ref,
                 tw_u_ref, tw_p_ref, fresh_ref, ia_ref, ib_ref,
                 noc_e_ref, noc_m_ref, cycles_ref, energy_ref, *,
@@ -46,46 +55,59 @@ def _score_body(bounds_ref, cum_ref, rel_i_ref, rel_w_ref, rel_o_ref,
                 zsf_of, mem_bw_of, e_read_of, e_write_of,
                 comp_cycles_of, dyn0_of, leak_of, noc_bw_of):
     """The scoring pipeline, shared by both kernels.  The `*_of` getters
-    return either static Python floats (single-arch) or [Bm] row vectors
-    (multi-arch) — the math broadcasts identically."""
-    bounds = bounds_ref[...]                    # [Bm, S]
+    return either static Python floats (single-arch) or [1, Bm] row
+    vectors (multi-arch) — the math broadcasts identically."""
+    bounds = bounds_ref[...]                    # [S, Bm]
     cum = cum_ref[...]
-    rel = {0: rel_i_ref[...], 1: rel_w_ref[...], 2: rel_o_ref[...]}
-    bm = bounds.shape[0]
-    pos = jax.lax.broadcasted_iota(jnp.float32, bounds.shape, 1) + 1.0
+    rel_refs = (rel_i_ref, rel_w_ref, rel_o_ref)
+    rel = [r[...] for r in rel_refs]
+    n_slots, bm = bounds.shape
+    # Mosaic's iota is integer-only: build 1-based slot positions in int32
+    pos = (jax.lax.broadcasted_iota(jnp.int32, bounds.shape, 0) + 1
+           ).astype(jnp.float32)
     active = jnp.where(bounds > 1.0, 1.0, 0.0)
 
-    reads = [jnp.zeros((bm,), jnp.float32) for _ in range(n_mem)]
-    writes = [jnp.zeros((bm,), jnp.float32) for _ in range(n_mem)]
-    raw = [jnp.zeros((bm,), jnp.float32) for _ in range(n_mem)]
-    noc_words = jnp.zeros((bm,), jnp.float32)
+    zero = jnp.zeros((1, bm), jnp.float32)
+    reads = [zero] * n_mem
+    writes = [zero] * n_mem
+    raw = [zero] * n_mem
+    noc_words = zero
     dyn = dyn0_of(bm)
 
     L1 = len(vis)
     for j in range(L1):
         v = float(vis[j])
-        i_a = ia_ref[:, j]
-        i_b = ib_ref[:, j]
-        nm = noc_m_ref[:, j]
+        i_a = _row(ia_ref, j)
+        i_b = _row(ib_ref, j)
+        nm = _row(noc_m_ref, j)
         visible = jnp.where(pos <= v, 1.0, 0.0)
         is_term = j == L1 - 1
         for t in range(3):
-            tw_u = tw_u_ref[:, j, t]
-            tw_p = tw_p_ref[:, j, t]
+            tw_u = _row(tw_u_ref, j * 3 + t)
+            tw_p = _row(tw_p_ref, j * 3 + t)
             r = visible * rel[t] * active
-            k1 = jnp.max(r * pos, axis=1)                    # [Bm] 1-based
+            k1 = jnp.max(r * pos, axis=0, keepdims=True)    # [1,Bm] 1-based
             has = k1 > 0.5
-            oh = jnp.where(pos == jnp.maximum(k1, 1.0)[:, None], 1.0, 0.0)
-            p_k = jnp.where(has, jnp.sum(cum * oh, axis=1), 1.0)
-            b_k = jnp.where(has, jnp.sum(bounds * oh, axis=1), 1.0)
+            oh = jnp.where(pos == jnp.maximum(k1, 1.0), 1.0, 0.0)
+            p_k = jnp.where(has, jnp.sum(cum * oh, axis=0, keepdims=True),
+                            1.0)
+            b_k = jnp.where(has, jnp.sum(bounds * oh, axis=0,
+                                         keepdims=True), 1.0)
             vv = p_k
             outer = p_k / b_k
             zsf = zsf_of(j, t)
-            ne = noc_e_ref[:, j, t]
+            ne = _row(noc_e_ref, j * 3 + t)
             if t == 2:                                        # output
-                relk = jnp.where((r * jnp.where(pos <= k1[:, None], 1.0,
-                                                0.0)) > 0, bounds, 1.0)
-                dd = jnp.where(has, jnp.prod(relk, axis=1), 1.0)
+                # product of the relevant active bounds at or inside k1,
+                # unrolled over the visible slots (Mosaic has no
+                # reduce_prod); slots past `v` are never visible
+                dd = jnp.ones((1, bm), jnp.float32)
+                for s in range(min(int(v), n_slots)):
+                    b_s = _row(bounds_ref, s)
+                    take = ((_row(rel_refs[t], s) > 0) & (b_s > 1.0)
+                            & (float(s + 1) <= k1))
+                    dd = dd * jnp.where(take, b_s, 1.0)
+                dd = jnp.where(has, dd, 1.0)
                 p_rd = i_a * (vv - dd) * tw_u
                 p_wr = i_a * vv * tw_u
                 reads[j] += p_rd * zsf
@@ -102,7 +124,7 @@ def _score_body(bounds_ref, cum_ref, rel_i_ref, rel_w_ref, rel_o_ref,
                 dyn += nw * zsf * ne
             else:
                 if t == 0:                                    # input: halo
-                    fr = jnp.sum(fresh_ref[:, j, :] * oh, axis=1)
+                    fr = jnp.sum(fresh_ref[j] * oh, axis=0, keepdims=True)
                     words = outer * (tw_u + (b_k - 1.0) * fr)
                     words = jnp.where(has, words, tw_u)
                 else:
@@ -118,10 +140,10 @@ def _score_body(bounds_ref, cum_ref, rel_i_ref, rel_w_ref, rel_o_ref,
                 noc_words += nw
                 dyn += nw * zsf * ne
 
-    pes = ib_ref[:, L1 - 1]                     # instances at compute leaf
+    pes = _row(ib_ref, L1 - 1)                  # instances at compute leaf
     cycles = comp_cycles_of(pes)
     for m in range(n_mem):
-        inst_m = ia_ref[:, m]                   # parent of pair m = level m
+        inst_m = _row(ia_ref, m)                # parent of pair m = level m
         cycles = jnp.maximum(cycles, raw[m] / (mem_bw_of(m) * inst_m))
         dyn += reads[m] * e_read_of(m) + writes[m] * e_write_of(m)
     cycles = jnp.maximum(cycles, noc_words / noc_bw_of())
@@ -154,7 +176,7 @@ def _score_kernel(bounds_ref, cum_ref, rel_i_ref, rel_w_ref, rel_o_ref,
         e_write_of=lambda m: e_write[m],
         comp_cycles_of=lambda pes: macs / (jnp.maximum(pes, 1.0)
                                            * macs_per_pe * pipeline),
-        dyn0_of=lambda bm: jnp.full((bm,), eff_macs * mac_energy,
+        dyn0_of=lambda bm: jnp.full((1, bm), eff_macs * mac_energy,
                                     jnp.float32),
         leak_of=lambda: leak_rate,
         noc_bw_of=lambda: noc_bw)
@@ -169,60 +191,60 @@ def _score_kernel_multi(bounds_ref, cum_ref, rel_i_ref, rel_w_ref,
     hardware/workload constants (same contract as
     `core.batch_eval.evaluate_batch_multi`):
 
-      zsf     [Bm, L1, 3]  zero-skip factor per chain pair per tensor
-      mem_par [Bm, Lm, 3]  (bandwidth, read_e, write_e) per memory level
-      hw_row  [Bm, 4]      (comp_scale, eff_mac_pj, leak_rate, noc_bw)
-                           with comp_scale = macs / (macs_per_pe * pipe)
+      zsf     [L1*3, Bm]  zero-skip factor per chain pair per tensor
+      mem_par [Lm*3, Bm]  (bandwidth, read_e, write_e) per memory level
+      hw_row  [4, Bm]     (comp_scale, eff_mac_pj, leak_rate, noc_bw)
+                          with comp_scale = macs / (macs_per_pe * pipe)
     """
     _score_body(
         bounds_ref, cum_ref, rel_i_ref, rel_w_ref, rel_o_ref,
         tw_u_ref, tw_p_ref, fresh_ref, ia_ref, ib_ref,
         noc_e_ref, noc_m_ref, cycles_ref, energy_ref,
         vis=vis, n_mem=n_mem,
-        zsf_of=lambda j, t: zsf_ref[:, j, t],
-        mem_bw_of=lambda m: mem_par_ref[:, m, 0],
-        e_read_of=lambda m: mem_par_ref[:, m, 1],
-        e_write_of=lambda m: mem_par_ref[:, m, 2],
-        comp_cycles_of=lambda pes: hw_row_ref[:, 0]
+        zsf_of=lambda j, t: _row(zsf_ref, j * 3 + t),
+        mem_bw_of=lambda m: _row(mem_par_ref, m * 3),
+        e_read_of=lambda m: _row(mem_par_ref, m * 3 + 1),
+        e_write_of=lambda m: _row(mem_par_ref, m * 3 + 2),
+        comp_cycles_of=lambda pes: _row(hw_row_ref, 0)
         / jnp.maximum(pes, 1.0),
-        dyn0_of=lambda bm: hw_row_ref[:, 1],
-        leak_of=lambda: hw_row_ref[:, 2],
-        noc_bw_of=lambda: hw_row_ref[:, 3])
+        dyn0_of=lambda bm: _row(hw_row_ref, 1),
+        leak_of=lambda: _row(hw_row_ref, 2),
+        noc_bw_of=lambda: _row(hw_row_ref, 3))
+
+
+def _lane_call(kern, arrays, block: int, interpret: bool, name: str):
+    """pallas_call over the lane (mapping) axis: every input is [..., B]
+    and is tiled [..., block]; outputs are (cycles, energy) as [1, B]."""
+    b = arrays[0].shape[-1]
+    assert b % block == 0, (b, block)
+
+    def spec(a):
+        lead = a.shape[:-1]
+        return pl.BlockSpec(lead + (block,),
+                            lambda i, _n=len(lead): (0,) * _n + (i,))
+
+    out_spec = pl.BlockSpec((1, block), lambda i: (0, i))
+    out_shape = jax.ShapeDtypeStruct((1, b), jnp.float32)
+    return pl.pallas_call(
+        kern,
+        grid=(b // block,),
+        in_specs=[spec(a) for a in arrays],
+        out_specs=[out_spec, out_spec],
+        out_shape=[out_shape, out_shape],
+        interpret=interpret,
+        name=name,
+    )(*arrays)
 
 
 def mapspace_eval_fwd(bounds, cum, rel_i, rel_w, rel_o, tw_u, tw_p, fresh,
                       ia, ib, noc_e, noc_m, *, static: dict,
                       block: int = 256, interpret: bool = False):
-    """All array args: leading mapping axis B (multiple of `block`).
-    Returns (cycles [B], energy [B])."""
-    b, s = bounds.shape
-    l1 = tw_u.shape[1]
-    assert b % block == 0, (b, block)
-    grid = (b // block,)
+    """All array args: trailing mapping axis B (multiple of `block`).
+    Returns (cycles [1, B], energy [1, B])."""
     kern = functools.partial(_score_kernel, **static)
-    row = lambda i: (i, 0)
-    row3 = lambda i: (i, 0, 0)
-    return pl.pallas_call(
-        kern,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block, s), row), pl.BlockSpec((block, s), row),
-            pl.BlockSpec((block, s), row), pl.BlockSpec((block, s), row),
-            pl.BlockSpec((block, s), row),
-            pl.BlockSpec((block, l1, 3), row3),
-            pl.BlockSpec((block, l1, 3), row3),
-            pl.BlockSpec((block, l1, s), row3),
-            pl.BlockSpec((block, l1), row), pl.BlockSpec((block, l1), row),
-            pl.BlockSpec((block, l1, 3), row3),
-            pl.BlockSpec((block, l1), row),
-        ],
-        out_specs=[pl.BlockSpec((block,), lambda i: (i,)),
-                   pl.BlockSpec((block,), lambda i: (i,))],
-        out_shape=[jax.ShapeDtypeStruct((b,), jnp.float32),
-                   jax.ShapeDtypeStruct((b,), jnp.float32)],
-        interpret=interpret,
-    )(bounds, cum, rel_i, rel_w, rel_o, tw_u, tw_p, fresh, ia, ib,
-      noc_e, noc_m)
+    return _lane_call(kern, [bounds, cum, rel_i, rel_w, rel_o, tw_u, tw_p,
+                             fresh, ia, ib, noc_e, noc_m],
+                      block, interpret, "mapspace_eval")
 
 
 def mapspace_eval_multi_fwd(bounds, cum, rel_i, rel_w, rel_o, tw_u, tw_p,
@@ -230,38 +252,11 @@ def mapspace_eval_multi_fwd(bounds, cum, rel_i, rel_w, rel_o, tw_u, tw_p,
                             hw_row, *, static: dict, block: int = 256,
                             interpret: bool = False):
     """Multi-architecture forward: the twelve per-mapping tensors plus
-    per-row hardware arrays (zsf [B, L1, 3], mem_par [B, Lm, 3],
-    hw_row [B, 4]).  All array args share the leading mapping axis B
-    (a multiple of `block`).  Returns (cycles [B], energy [B])."""
-    b, s = bounds.shape
-    l1 = tw_u.shape[1]
-    n_mem = mem_par.shape[1]
-    assert b % block == 0, (b, block)
-    grid = (b // block,)
+    per-row hardware arrays (zsf [L1*3, B], mem_par [Lm*3, B],
+    hw_row [4, B]).  All array args share the trailing mapping axis B
+    (a multiple of `block`).  Returns (cycles [1, B], energy [1, B])."""
     kern = functools.partial(_score_kernel_multi, **static)
-    row = lambda i: (i, 0)
-    row3 = lambda i: (i, 0, 0)
-    return pl.pallas_call(
-        kern,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block, s), row), pl.BlockSpec((block, s), row),
-            pl.BlockSpec((block, s), row), pl.BlockSpec((block, s), row),
-            pl.BlockSpec((block, s), row),
-            pl.BlockSpec((block, l1, 3), row3),
-            pl.BlockSpec((block, l1, 3), row3),
-            pl.BlockSpec((block, l1, s), row3),
-            pl.BlockSpec((block, l1), row), pl.BlockSpec((block, l1), row),
-            pl.BlockSpec((block, l1, 3), row3),
-            pl.BlockSpec((block, l1), row),
-            pl.BlockSpec((block, l1, 3), row3),
-            pl.BlockSpec((block, n_mem, 3), row3),
-            pl.BlockSpec((block, 4), row),
-        ],
-        out_specs=[pl.BlockSpec((block,), lambda i: (i,)),
-                   pl.BlockSpec((block,), lambda i: (i,))],
-        out_shape=[jax.ShapeDtypeStruct((b,), jnp.float32),
-                   jax.ShapeDtypeStruct((b,), jnp.float32)],
-        interpret=interpret,
-    )(bounds, cum, rel_i, rel_w, rel_o, tw_u, tw_p, fresh, ia, ib,
-      noc_e, noc_m, zsf, mem_par, hw_row)
+    return _lane_call(kern, [bounds, cum, rel_i, rel_w, rel_o, tw_u, tw_p,
+                             fresh, ia, ib, noc_e, noc_m, zsf, mem_par,
+                             hw_row],
+                      block, interpret, "mapspace_eval_multi")

@@ -24,8 +24,8 @@ from typing import List, Sequence, Tuple
 import jax.numpy as jnp
 import numpy as np
 
-from ...core.batch_eval import (RELEVANT, SLIDING, HwStatic, make_static,
-                                pack, sig_of,
+from ...core.batch_eval import (RELEVANT, SLIDING, HwStatic, bucket,
+                                make_static, pack, sig_of,
                                 tile_words_np as _tile_words_np)
 from ...core.mapping import Mapping
 from ...core.workload import N_, M_, C_, R_, S_, E_, F_
@@ -153,16 +153,28 @@ def _hw_numerics(st: HwStatic):
         e_write=tuple(st.write_e))
 
 
-def _pad_block(arrays, B: int, block: int):
-    pad = (-B) % block
-    if not pad:
-        return arrays
-    return [np.concatenate([a, np.repeat(a[:1], pad, 0)], 0)
-            for a in arrays]
+def _to_lanes(arrays, block: int):
+    """Row-major per-mapping arrays [B, ...] -> the kernel's feature-major
+    layout with the mapping axis last, padded (repeating row 0) to the
+    power-of-2 `bucket` the jnp path uses, so row counts reuse compiled
+    shapes.  Index 7 is `fresh` [B, L1, S] -> [L1, S, Bp]; every other
+    array flattens its features -> [F, Bp].  -> (device arrays, block)."""
+    B = arrays[0].shape[0]
+    bp = bucket(B)
+    block = min(block, bp)
+    assert bp % block == 0, (bp, block)
+    out = []
+    for k, a in enumerate(arrays):
+        if bp > B:
+            a = np.concatenate([a, np.repeat(a[:1], bp - B, 0)], 0)
+        a = (a.transpose(1, 2, 0) if k == 7
+             else a.reshape(bp, -1).T)
+        out.append(jnp.asarray(np.ascontiguousarray(a, np.float32)))
+    return out, block
 
 
 def pack_for_kernel_arrays(st: HwStatic, factors, rank, block: int = 256):
-    """Pre-packed arrays -> (kernel arrays, static dict, n) for the
+    """Pre-packed arrays -> (kernel arrays, static dict, n, block) for the
     single-arch kernel."""
     arrays, zs_parent, Lm, L1, _ = _mapping_rows(st, factors, rank)
     B = arrays[0].shape[0]
@@ -175,8 +187,8 @@ def pack_for_kernel_arrays(st: HwStatic, factors, rank, block: int = 256):
         pipeline=hw["pipeline"], mac_energy=hw["mac_energy"],
         eff_macs=hw["eff_macs"], leak_rate=hw["leak_rate"],
         noc_bw=hw["noc_bw"], n_mem=Lm)
-    arrays = [jnp.asarray(a) for a in _pad_block(arrays, B, block)]
-    return arrays, static, B
+    arrays, block = _to_lanes(arrays, block)
+    return arrays, static, B, block
 
 
 def pack_for_kernel(mappings: Sequence[Mapping], block: int = 256):
@@ -192,19 +204,20 @@ def pack_for_kernel(mappings: Sequence[Mapping], block: int = 256):
 def mapspace_eval_arrays(st: HwStatic, factors, rank, *, block: int = 256,
                          interpret: bool = False):
     """-> (cycles [n], energy [n]) float32 arrays from packed arrays."""
-    arrays, static, n = pack_for_kernel_arrays(st, factors, rank, block)
+    arrays, static, n, block = pack_for_kernel_arrays(st, factors, rank,
+                                                      block)
     cycles, energy = mapspace_eval_fwd(*arrays, static=static, block=block,
                                        interpret=interpret)
-    return np.asarray(cycles[:n]), np.asarray(energy[:n])
+    return np.asarray(cycles[0, :n]), np.asarray(energy[0, :n])
 
 
 def mapspace_eval(mappings: Sequence[Mapping], *, block: int = 256,
                   interpret: bool = False):
     """-> (cycles [n], energy [n]) float32 arrays (legacy object API)."""
-    arrays, static, n = pack_for_kernel(mappings, block)
+    arrays, static, n, block = pack_for_kernel(mappings, block)
     cycles, energy = mapspace_eval_fwd(*arrays, static=static, block=block,
                                        interpret=interpret)
-    return np.asarray(cycles[:n]), np.asarray(energy[:n])
+    return np.asarray(cycles[0, :n]), np.asarray(energy[0, :n])
 
 
 # ---------------------------------------------------------------------------
@@ -218,7 +231,8 @@ def pack_for_kernel_multi(groups: List[Tuple[HwStatic, np.ndarray,
 
     Every group must share the structural `BatchSig` (level layout,
     tensor set, depthwise) — exactly the `evaluate_batch_multi` contract;
-    the numeric hardware/workload constants become [B, ...] arrays:
+    the numeric hardware/workload constants become per-row arrays (built
+    [B, ...] here, handed to the kernel feature-major by `_to_lanes`):
 
       zsf     [B, L1, 3]  zero-skip factor per chain pair per tensor
       mem_par [B, Lm, 3]  (bandwidth, read_e, write_e) per memory level
@@ -249,8 +263,8 @@ def pack_for_kernel_multi(groups: List[Tuple[HwStatic, np.ndarray,
     B = fused[0].shape[0]
     Lm = len(sig0.mem_idx)
     static = dict(vis=tuple((jj + 1) * 7 for jj in range(Lm)), n_mem=Lm)
-    fused = [jnp.asarray(a) for a in _pad_block(fused, B, block)]
-    return fused, static, B
+    fused, block = _to_lanes(fused, block)
+    return fused, static, B, block
 
 
 def mapspace_eval_multi(groups: List[Tuple[HwStatic, np.ndarray,
@@ -258,8 +272,8 @@ def mapspace_eval_multi(groups: List[Tuple[HwStatic, np.ndarray,
                         block: int = 256, interpret: bool = False):
     """-> (cycles [n], energy [n]) over the concatenated group rows, one
     kernel call for the whole cross-architecture batch."""
-    fused, static, n = pack_for_kernel_multi(groups, block)
+    fused, static, n, block = pack_for_kernel_multi(groups, block)
     cycles, energy = mapspace_eval_multi_fwd(*fused, static=static,
                                              block=block,
                                              interpret=interpret)
-    return np.asarray(cycles[:n]), np.asarray(energy[:n])
+    return np.asarray(cycles[0, :n]), np.asarray(energy[0, :n])

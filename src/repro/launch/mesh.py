@@ -18,12 +18,15 @@ import numpy as np
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
-    """Arbitrary mesh (elastic re-mesh / tests)."""
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    """Arbitrary mesh (elastic re-mesh / tests).  Axes are Auto: the
+    model's `with_sharding_constraint` hooks need Auto axes, and
+    `jax.make_mesh` defaults to Explicit ones."""
+    return jax.make_mesh(tuple(shape), tuple(axes), axis_types=(
+        jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_host_test_mesh(n_devices: int = 8, model: int = 2):

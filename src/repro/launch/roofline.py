@@ -43,15 +43,9 @@ _IOTA_RE = re.compile(r"replica_groups=\[(\d+),(\d+)\]")
 
 
 def xla_cost_analysis(compiled) -> Dict[str, float]:
-    """Normalized `compiled.cost_analysis()` properties dict.
-
-    Depending on the JAX version, cost_analysis() returns either a flat
-    dict or a one-element list of per-program dicts; callers always want
-    the entry-program dict (use .get("flops") / .get("bytes accessed"))."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca)
+    """`compiled.cost_analysis()` as a plain dict of the entry program's
+    properties (use .get("flops") / .get("bytes accessed"))."""
+    return dict(compiled.cost_analysis())
 
 
 def _shape_bytes(shape_str: str) -> float:

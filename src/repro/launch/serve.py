@@ -141,4 +141,6 @@ def main(argv: Optional[List[str]] = None):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

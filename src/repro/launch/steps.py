@@ -6,6 +6,7 @@ or execution (real run).
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 from typing import Any, Dict, Optional, Tuple
@@ -18,11 +19,23 @@ from ..configs.base import ModelConfig
 from ..configs.shapes import ShapeSpec
 from ..models import decode_step, forward, init_model
 from ..models.model import cache_specs
-from ..parallel.sharding import (ShardingRules, install_activation_sharding,
+from ..parallel.sharding import (ShardingRules, clear_activation_sharding,
+                                 install_activation_sharding,
                                  param_shardings, spec_to_pspec)
 from ..train.optimizer import OptConfig, init_opt_state
 from ..train.train_step import TrainConfig, TrainState, make_train_step
 from .specs import batch_logical_specs, decode_specs, input_specs
+
+
+@contextlib.contextmanager
+def _activation_sharding(mesh, rules):
+    """The model's sharding hooks, installed while a step is traced and
+    cleared after, so they never leak into a later trace."""
+    install_activation_sharding(mesh, rules)
+    try:
+        yield
+    finally:
+        clear_activation_sharding()
 
 
 def _repl(mesh):
@@ -90,8 +103,8 @@ def build_train_step(cfg: ModelConfig, mesh: Mesh, rules: ShardingRules,
     step = make_train_step(cfg, opt_cfg, tc)
 
     def wrapped(state, batch):
-        install_activation_sharding(mesh, rules)
-        return step(state, batch)
+        with _activation_sharding(mesh, rules):
+            return step(state, batch)
 
     metrics_sh = {"loss": _repl(mesh), "grad_norm": _repl(mesh),
                   "lr": _repl(mesh)}
@@ -112,8 +125,9 @@ def build_prefill_step(cfg: ModelConfig, mesh: Mesh, rules: ShardingRules,
             for k, v in in_specs.items()}
 
     def prefill(params, batch):
-        install_activation_sharding(mesh, rules)
-        return forward(params, cfg, batch, remat=remat, logits_mode="last")
+        with _activation_sharding(mesh, rules):
+            return forward(params, cfg, batch, remat=remat,
+                           logits_mode="last")
 
     jit_fn = jax.jit(prefill, in_shardings=(p_sh, b_sh))
     return jit_fn, (params_shapes, in_specs), (p_sh, b_sh)
@@ -133,9 +147,9 @@ def build_decode_step(cfg: ModelConfig, mesh: Mesh, rules: ShardingRules,
     t_sh = _leaf_sharding(mesh, rules, ("batch",), token_spec)
 
     def serve_step(params, cache, token, pos):
-        install_activation_sharding(mesh, rules)
-        return decode_step(params, cfg, cache, token, pos,
-                           mla_absorb=mla_absorb)
+        with _activation_sharding(mesh, rules):
+            return decode_step(params, cfg, cache, token, pos,
+                               mla_absorb=mla_absorb)
 
     jit_fn = jax.jit(serve_step,
                      in_shardings=(p_sh, c_sh, t_sh, _repl(mesh)),

@@ -63,6 +63,10 @@ class RunManifest:
     phase_times: Dict[str, float]        # seconds by driver phase
     best_arch: Optional[str]
     best_value: Optional[float]
+    # the device the scores came from, as JAX reports it
+    device_platform: Optional[str] = None
+    device_kind: Optional[str] = None
+    device_count: Optional[int] = None
     version: int = MANIFEST_VERSION
 
     def to_dict(self) -> Dict[str, Any]:
@@ -100,8 +104,9 @@ def build_manifest(report, space, *, wall_time_s: float,
     run_id = "run-" + hashlib.sha256(rid_blob.encode()).hexdigest()[:16]
     try:
         jb = jax.default_backend()
+        dev = jax.local_devices()
     except Exception:
-        jb = None
+        jb, dev = None, []
     counters = {
         "n_evaluated": report.n_evaluated,
         "n_revisits": report.n_revisits,
@@ -124,4 +129,7 @@ def build_manifest(report, space, *, wall_time_s: float,
         phase_times=(tracer.phase_times() if tracer is not None
                      and getattr(tracer, "enabled", False) else {}),
         best_arch=(report.best.hardware.name if report.best else None),
-        best_value=(report.goal_value() if report.best else None))
+        best_value=(report.goal_value() if report.best else None),
+        device_platform=dev[0].platform if dev else None,
+        device_kind=dev[0].device_kind if dev else None,
+        device_count=len(dev) if dev else None)

@@ -185,8 +185,6 @@ def moe_ep_shard_map(p, cfg, x, mesh: Mesh, rules: ShardingRules):
     is the textbook EP dataflow (GShard/Switch) written with shard_map so
     SPMD cannot mis-place the dispatch scatter.  Returns None (caller falls
     back to the global path) when the mesh/shapes don't fit the pattern."""
-    from jax.experimental.shard_map import shard_map
-
     e = cfg.n_experts
     if "model" not in mesh.axis_names:
         return None
@@ -230,8 +228,8 @@ def moe_ep_shard_map(p, cfg, x, mesh: Mesh, rules: ShardingRules):
     ws = (p["w_gate"], p["w_up"], p["w_down"]) if has_up \
         else (p["w_gate"], p["w_down"])
     in_specs = (xspec, P()) + (wspec,) * len(ws)
-    return shard_map(local, mesh=mesh, in_specs=in_specs, out_specs=xspec,
-                     check_rep=False)(x, p["router"], *ws)
+    return jax.shard_map(local, mesh=mesh, in_specs=in_specs,
+                         out_specs=xspec, check_vma=False)(x, p["router"], *ws)
 
 
 def masked_embedding_lookup(table, tokens, mesh: Mesh,
@@ -241,8 +239,6 @@ def masked_embedding_lookup(table, tokens, mesh: Mesh,
     clamped + masked to zero) and a psum over 'model' assembles the row.
     Falls back to a plain gather when the vocab doesn't divide the model
     axis (the table is then replicated by the divisibility rule anyway)."""
-    from jax.experimental.shard_map import shard_map
-
     vocab = table.shape[0]
     if "model" not in mesh.axis_names or vocab % mesh.shape["model"] != 0:
         return table[tokens]
@@ -261,6 +257,6 @@ def masked_embedding_lookup(table, tokens, mesh: Mesh,
         vals = jnp.where(ok[..., None], vals, 0)
         return jax.lax.psum(vals, "model")
 
-    return shard_map(local, mesh=mesh,
-                     in_specs=(P("model", None), tok_spec),
-                     out_specs=out_spec, check_rep=False)(table, tokens)
+    return jax.shard_map(local, mesh=mesh,
+                         in_specs=(P("model", None), tok_spec),
+                         out_specs=out_spec, check_vma=False)(table, tokens)

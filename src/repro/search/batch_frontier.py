@@ -366,8 +366,8 @@ def _kernel_group(idxs: List[int], jobs, arrays: List[_JobArrays],
     only cycles/energy).  With several local devices and a large enough
     group, whole jobs are split into per-device sub-calls (row-wise
     independent, so winners are unchanged)."""
-    from ..core.backend import (_kernel_block, default_interpret,
-                                device_scope, validity_mask_arrays)
+    from ..core.backend import (default_interpret, device_scope,
+                                validity_mask_arrays)
     from ..kernels.mapspace_eval import ops as _kernel_ops
 
     counts = [arrays[i].factors.shape[0] for i in idxs]
@@ -375,12 +375,10 @@ def _kernel_group(idxs: List[int], jobs, arrays: List[_JobArrays],
     cyc_parts: List[np.ndarray] = []
     en_parts: List[np.ndarray] = []
     for sub, dev in _kernel_shard_plan(idxs, counts):
-        sub_total = sum(arrays[i].factors.shape[0] for i in sub)
         with device_scope(dev):
             cycles, energy = _kernel_ops.mapspace_eval_multi(
                 [(arrays[i].st, arrays[i].factors, arrays[i].rank)
-                 for i in sub],
-                block=_kernel_block(sub_total, 256), interpret=interpret)
+                 for i in sub], interpret=interpret)
         cyc_parts.append(np.asarray(cycles, np.float64))
         en_parts.append(np.asarray(energy, np.float64))
     cycles = np.concatenate(cyc_parts)
@@ -494,18 +492,10 @@ def per_arch_best(jobs: Sequence[MapspaceJob], goal: str = "edp",
             batch = job.packed if job.packed is not None else job.mappings
             mat = (job.packed.materialize if job.packed is not None
                    else job.mappings.__getitem__)
-            best_i = None
             if use_batch and job.n_rows() >= 64:
-                try:
-                    best_i = batch_best_index(batch, goal, backend=backend)
-                    best_v = score(evaluate_mapping(mat(best_i)))
-                except Exception:
-                    if backend != "jnp":
-                        raise       # an explicit engine must fail loudly —
-                        # a silent jnp fallback would cache its winner
-                        # under the pallas cache key
-                    best_i = None
-            if best_i is None:
+                best_i = batch_best_index(batch, goal, backend=backend)
+                best_v = score(evaluate_mapping(mat(best_i)))
+            else:
                 best_v = _math.inf
                 best_i = 0
                 for i in range(job.n_rows()):

@@ -148,6 +148,25 @@ def test_per_arch_best_backend_param():
     assert [b.index for b in got] == [b.index for b in ref]
 
 
+@pytest.mark.parametrize("entry", ["find_optimal_mapping", "per_arch_best"])
+def test_jnp_scorer_error_propagates(monkeypatch, entry):
+    """A batch-scorer failure (a device compile or memory error on a chip)
+    must surface, never be re-scored on the host by the scalar loop."""
+    import repro.core.batch_eval as be
+    from repro.core import find_optimal_mapping
+
+    def boom(*a, **k):
+        raise RuntimeError("scorer failed on the device")
+
+    monkeypatch.setattr(be, "batch_best_index", boom)
+    with pytest.raises(RuntimeError, match="scorer failed on the device"):
+        if entry == "find_optimal_mapping":
+            find_optimal_mapping(TW.intra[2], _arch(), MapperConfig(
+                max_mappings=300, seed=2), backend="jnp")
+        else:
+            per_arch_best(_jobs(bypass_second=True), "edp", backend="jnp")
+
+
 @pytest.mark.parametrize("batching", ["fused", "per-arch"])
 def test_run_search_same_best_under_either_backend(batching):
     space = ArchSpace.spatial(num_pes=(16, 64), rf_words=(64,),

@@ -331,6 +331,12 @@ def test_manifest_written_and_round_trips(traced_run):
     assert m.counters["cache"] == rep.cache_stats
     assert m.space_digest and m.backend == rep.backend
     assert m.phase_times.keys() == rep.phase_times.keys()
+    # the device stamp names what JAX scored on, and survives the trip
+    import jax
+    dev = jax.local_devices()
+    assert (m.device_platform, m.device_kind, m.device_count) == \
+        (dev[0].platform, dev[0].device_kind, len(dev))
+    assert m.jax_backend == jax.default_backend()
     # manifests live outside the GC-swept cache root
     cache = ResultCache(path=cache_dir, max_disk_entries=0)
     evicted = cache.gc()
