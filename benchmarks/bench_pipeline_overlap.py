@@ -29,7 +29,6 @@ from collections import defaultdict
 
 from repro.core import (Conv2D, FC, MapperConfig, TaskDescription,
                         generate_arch_space)
-from repro.core.batch_eval import reset_jit_registry
 from repro.search import run_search
 
 from .common import claim
@@ -98,7 +97,6 @@ def run(max_mappings=2000):
         return time.time() - t0, rep
 
     jax.clear_caches()
-    reset_jit_registry()
     arm(False)                          # warmup: compile every executable
     seq_s, seq = arm(False)
     str_s, stream = arm(True)
@@ -129,10 +127,10 @@ def run(max_mappings=2000):
 
     jit = stream.summary()["jit"]
     claim(res, "warm streaming arm reuses every (sig, bucket, device) "
-          "executable (dispatches counted, zero recompiles)",
-          jit["counters"].get("jit.dispatches", 0) >= len(archs)
-          and "jit.compiles" not in jit["counters"],
-          f"dispatches={jit['counters'].get('jit.dispatches', 0):.0f}")
+          "executable (dispatches counted, zero compiles)",
+          jit["dispatches"] >= len(archs) and jit["compile"]["n"] == 0,
+          f"dispatches={jit['dispatches']} "
+          f"compiles={jit['compile']['n']}")
 
     if backend != "cpu":
         floor = 1.25 if max_mappings <= 600 else 1.4

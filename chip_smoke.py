@@ -107,8 +107,9 @@ def row_counts(tracer):
 
 
 def jit_counts(report):
-    c = report.summary()["jit"]["counters"]
-    return (int(c.get("jit.compiles", 0)), int(c.get("jit.dispatches", 0)))
+    """(XLA compiles, fused dispatches) of a traced search."""
+    jit = report.summary()["jit"]
+    return jit["compile"]["n"], jit["dispatches"]
 
 
 def run_phase(name, comp, fn):

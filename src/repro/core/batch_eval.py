@@ -471,39 +471,13 @@ def score_devices() -> Tuple:
     return tuple(jax.local_devices())
 
 
-# (BatchSig-structural-key, bucket_rows, device) combos dispatched so far
-# in this process — a host-side mirror of jit's executable cache, reset
-# alongside `jax.clear_caches()` via `reset_jit_registry()`.
-_JIT_SEEN: set = set()
-
-
-def _sig_tag(sig) -> str:
-    """Short stable label for a BatchSig, used in per-sig counter names:
-    levels/memory/routing counts plus depthwise/weight flags."""
-    return (f"L{sig.n_levels}m{len(sig.mem_idx)}r{len(sig.rout_idx)}"
-            f"{'dw' if sig.depthwise else ''}"
-            f"{'w' if sig.has_weight else ''}")
-
-
-def note_batch_dispatch(sig, bucket_rows: int, device=None) -> None:
+def note_batch_dispatch(bucket_rows: int) -> None:
     """Record one fused-batch dispatch into the ambient tracer's metrics:
-    `jit.dispatches`, the `jit.bucket_rows` histogram, and — when this
-    (sig, bucket, device) combo is new to the process, i.e. jit will
-    compile — `jit.compiles` plus a per-BatchSig compile counter."""
+    `jit.dispatches` and the `jit.bucket_rows` histogram of padded sizes.
+    Real compiles are JAX's own events (`repro.obs.jax_events`)."""
     m = current_tracer().metrics
     m.counter("jit.dispatches").inc()
     m.histogram("jit.bucket_rows").observe(float(bucket_rows))
-    combo = (sig, int(bucket_rows), None if device is None else str(device))
-    if combo not in _JIT_SEEN:
-        _JIT_SEEN.add(combo)
-        m.counter("jit.compiles").inc()
-        m.counter(f"jit.compiles[{_sig_tag(sig)}]").inc()
-
-
-def reset_jit_registry() -> None:
-    """Forget seen (sig, bucket, device) combos — call alongside
-    `jax.clear_caches()` so compile counters track reality."""
-    _JIT_SEEN.clear()
 
 
 # ---------------------------------------------------------------------------

@@ -314,13 +314,19 @@ def sample_index_rows(tables: MapspaceTables, cfg: MapperConfig,
 def candidate_index_rows(workload: Workload, hw: HardwareDesc,
                          cfg: MapperConfig):
     """-> (tables, fi, oi, bi): the full candidate set when it fits the
-    budget, the deduplicated vectorized sample otherwise."""
+    budget, the deduplicated vectorized sample otherwise.  Records the
+    `pack.tables` and `pack.rows` spans into the ambient tracer."""
+    from ..obs import current_tracer
+    tr = current_tracer()
     rng = random.Random(cfg.seed)
-    tables = mapspace_tables(workload, hw, cfg, rng)
-    if tables.total <= cfg.max_mappings:
-        fi, oi, bi = enumerate_index_rows(tables)
-    else:
-        fi, oi, bi = sample_index_rows(tables, cfg, cfg.seed)
+    with tr.span("pack.tables"):
+        tables = mapspace_tables(workload, hw, cfg, rng)
+    with tr.span("pack.rows", total=tables.total) as sp:
+        if tables.total <= cfg.max_mappings:
+            fi, oi, bi = enumerate_index_rows(tables)
+        else:
+            fi, oi, bi = sample_index_rows(tables, cfg, cfg.seed)
+        sp.set(rows=int(fi.shape[0]))
     return tables, fi, oi, bi
 
 

@@ -185,7 +185,8 @@ def build_packed_mapspace(workload: Workload, hw: HardwareDesc,
     """Array-native `build_mapspace`: enumerate/sample -> assemble ->
     validate -> prune, all batched; bit-exact with the object path.
 
-    Emits `pack` (enumeration/sampling + array assembly) and `validate`
+    Emits `pack` (enumeration/sampling + array assembly; child spans
+    `pack.tables`, `pack.rows`, `pack.assemble`) and `validate`
     (vectorized validity + §5.2 pruning) phase spans into the ambient
     `repro.obs` tracer (no-op by default)."""
     from ..obs import current_tracer
@@ -194,9 +195,10 @@ def build_packed_mapspace(workload: Workload, hw: HardwareDesc,
     with tr.span("pack", phase=True, workload=workload.name,
                  arch=hw.name) as sp:
         tables, fi, oi, bi = candidate_index_rows(workload, hw, cfg)
-        st = make_static(hw, workload)
-        factors, rank, store = assemble_arrays(
-            tables, st, workload.has_weight, fi, oi, bi)
+        with tr.span("pack.assemble", rows=int(fi.shape[0])):
+            st = make_static(hw, workload)
+            factors, rank, store = assemble_arrays(
+                tables, st, workload.has_weight, fi, oi, bi)
         sp.set(candidates=int(fi.shape[0]), total=tables.total)
     with tr.span("validate", phase=True, workload=workload.name) as sp:
         valid = packed_validity(hw, st, factors, store, cfg.act_reserve)
@@ -206,7 +208,6 @@ def build_packed_mapspace(workload: Workload, hw: HardwareDesc,
         # the valid set (mapper.build_mapspace semantics)
         idx = np.flatnonzero(keep if keep.any() else valid)
         sp.set(n_valid=n_valid, survivors=int(idx.shape[0]))
-    tr.metrics.histogram("mapspace.rows").observe(int(idx.shape[0]))
     return PackedMapspace(
         workload=workload, hardware=hw, static=st,
         factors=factors[idx], rank=rank[idx], store=store[idx],

@@ -29,6 +29,7 @@ from ...core.batch_eval import (RELEVANT, SLIDING, HwStatic, bucket,
                                 tile_words_np as _tile_words_np)
 from ...core.mapping import Mapping
 from ...core.workload import N_, M_, C_, R_, S_, E_, F_
+from ...obs import current_tracer
 from .kernel import mapspace_eval_fwd, mapspace_eval_multi_fwd
 
 
@@ -223,22 +224,10 @@ def mapspace_eval(mappings: Sequence[Mapping], *, block: int = 256,
 # ---------------------------------------------------------------------------
 # multi-architecture fused kernel batches
 # ---------------------------------------------------------------------------
-def pack_for_kernel_multi(groups: List[Tuple[HwStatic, np.ndarray,
-                                             np.ndarray]],
-                          block: int = 256):
-    """Rows of several single-(arch, workload) groups -> one fused kernel
-    batch with per-row hardware constants.
-
-    Every group must share the structural `BatchSig` (level layout,
-    tensor set, depthwise) — exactly the `evaluate_batch_multi` contract;
-    the numeric hardware/workload constants become per-row arrays (built
-    [B, ...] here, handed to the kernel feature-major by `_to_lanes`):
-
-      zsf     [B, L1, 3]  zero-skip factor per chain pair per tensor
-      mem_par [B, Lm, 3]  (bandwidth, read_e, write_e) per memory level
-      hw_row  [B, 4]      (comp_scale, eff_mac_pj, leak_rate, noc_bw)
-                          with comp_scale = macs / (macs_per_pe * pipeline)
-    """
+def _multi_rows(groups: List[Tuple[HwStatic, np.ndarray, np.ndarray]]):
+    """Rows of several single-(arch, workload) groups -> the fused
+    row-major arrays [B, ...] with per-row hardware constants, the
+    kernel's static dict and B (host numpy; see `pack_for_kernel_multi`)."""
     sig0 = sig_of(groups[0][0])
     per_group = []
     for st, factors, rank in groups:
@@ -260,9 +249,28 @@ def pack_for_kernel_multi(groups: List[Tuple[HwStatic, np.ndarray,
         per_group.append(arrays + [zsf, mem_par, hw_row])
     fused = [np.concatenate(parts, axis=0)
              for parts in zip(*per_group)]
-    B = fused[0].shape[0]
     Lm = len(sig0.mem_idx)
     static = dict(vis=tuple((jj + 1) * 7 for jj in range(Lm)), n_mem=Lm)
+    return fused, static, fused[0].shape[0]
+
+
+def pack_for_kernel_multi(groups: List[Tuple[HwStatic, np.ndarray,
+                                             np.ndarray]],
+                          block: int = 256):
+    """Rows of several single-(arch, workload) groups -> one fused kernel
+    batch with per-row hardware constants.
+
+    Every group must share the structural `BatchSig` (level layout,
+    tensor set, depthwise) — exactly the `evaluate_batch_multi` contract;
+    the numeric hardware/workload constants become per-row arrays (built
+    [B, ...] here, handed to the kernel feature-major by `_to_lanes`):
+
+      zsf     [B, L1, 3]  zero-skip factor per chain pair per tensor
+      mem_par [B, Lm, 3]  (bandwidth, read_e, write_e) per memory level
+      hw_row  [B, 4]      (comp_scale, eff_mac_pj, leak_rate, noc_bw)
+                          with comp_scale = macs / (macs_per_pe * pipeline)
+    """
+    fused, static, B = _multi_rows(groups)
     fused, block = _to_lanes(fused, block)
     return fused, static, B, block
 
@@ -271,9 +279,21 @@ def mapspace_eval_multi(groups: List[Tuple[HwStatic, np.ndarray,
                                            np.ndarray]], *,
                         block: int = 256, interpret: bool = False):
     """-> (cycles [n], energy [n]) over the concatenated group rows, one
-    kernel call for the whole cross-architecture batch."""
-    fused, static, n, block = pack_for_kernel_multi(groups, block)
-    cycles, energy = mapspace_eval_multi_fwd(*fused, static=static,
-                                             block=block,
-                                             interpret=interpret)
-    return np.asarray(cycles[0, :n]), np.asarray(energy[0, :n])
+    kernel call for the whole cross-architecture batch.  Each step is a
+    span of the ambient tracer: `kernel.pack` (host rows), `kernel.transfer`
+    (lane layout and copy to the device), `kernel.launch` (the call up to
+    its return: trace, lowering, program load, enqueue) and `kernel.fetch`
+    (the wait and the copy back)."""
+    tr = current_tracer()
+    with tr.span("kernel.pack",
+                 rows=sum(int(g[1].shape[0]) for g in groups)):
+        fused, static, n = _multi_rows(groups)
+    with tr.span("kernel.transfer", rows=n) as sp:
+        fused, block = _to_lanes(fused, block)
+        sp.set(bytes=sum(int(a.nbytes) for a in fused))
+    with tr.span("kernel.launch", rows=n):
+        cycles, energy = mapspace_eval_multi_fwd(*fused, static=static,
+                                                 block=block,
+                                                 interpret=interpret)
+    with tr.span("kernel.fetch", rows=n):
+        return np.asarray(cycles[0, :n]), np.asarray(energy[0, :n])

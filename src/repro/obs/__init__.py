@@ -1,9 +1,13 @@
 """repro.obs — dependency-free observability for the DSE pipeline.
 
-  trace      nestable spans + counters -> thread-safe TraceBuffer with
-             JSONL and Chrome trace_event (chrome://tracing / Perfetto)
-             export; `NULL_TRACER` is the zero-overhead default and
-             `activate()` scopes an ambient tracer for library code
+  trace      nestable spans -> thread-safe TraceBuffer with JSONL and
+             Chrome trace_event (chrome://tracing / Perfetto) export, each
+             recording span also a `jax.profiler.TraceAnnotation`;
+             `NULL_TRACER` is the zero-overhead default and `activate()`
+             scopes an ambient tracer (and the thread's role) for library
+             code
+  jax_events JAX's trace/lower/compile events as `jit.*` spans and its
+             compile-cache traffic as counters of the ambient tracer
   metrics    named counters / gauges / histograms (p50/p95/max) with a
              JSON-safe `snapshot()`
   progress   typed ProgressEvent stream (arch evaluated/skipped, cache
@@ -17,6 +21,7 @@ Instrumentation rules: spans are host-side only (never inside jit-traced
 code) and bracket the numpy conversion that forces async JAX dispatch, so
 device time lands in the span that launched the work.
 """
+from .jax_events import JIT_SPANS, jit_summary
 from .manifest import (MANIFEST_DIR, RunManifest, build_manifest, git_sha,
                        space_digest)
 from .metrics import (NULL_METRICS, Counter, Gauge, Histogram, Metrics,

@@ -10,6 +10,7 @@ cache root, never evicts provenance), and also exportable anywhere via
 from __future__ import annotations
 
 import dataclasses
+import functools
 import hashlib
 import json
 import os
@@ -21,8 +22,10 @@ MANIFEST_VERSION = 1
 MANIFEST_DIR = "manifests"
 
 
+@functools.lru_cache(maxsize=None)
 def git_sha(cwd: Optional[str] = None) -> Optional[str]:
-    """Best-effort commit sha of the working tree (None outside a repo)."""
+    """Best-effort commit sha of the working tree (None outside a repo),
+    asked of git once per process and directory."""
     try:
         out = subprocess.run(
             ["git", "rev-parse", "HEAD"], cwd=cwd or os.path.dirname(

@@ -2,8 +2,8 @@
 
 A `Metrics` registry rides on each `Tracer` (`tracer.metrics`) so the
 instrumented pipeline reports scalar statistics — cache hit splits, rows
-scored per backend, fused-group sizes, serve-slot occupancy — next to its
-spans.  Everything is thread-safe (one registry lock + per-instrument
+scored per backend, padded dispatch sizes, JAX compile-cache traffic,
+serve-slot occupancy — next to its spans.  Everything is thread-safe (one registry lock + per-instrument
 locks are avoided by keeping mutations O(1) under the registry lock);
 the `NULL_METRICS` twin is the zero-overhead off path.
 """

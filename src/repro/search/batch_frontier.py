@@ -137,6 +137,15 @@ def _group_jobs(jobs: Sequence[MapspaceJob], engine: str):
     return groups, kernel_groups, arrays, sizes
 
 
+def _eligible_rows(idxs: List[int], arrays: List[_JobArrays],
+                   engine: str) -> int:
+    """No-bypass rows of a jnp chunk that the pallas engine could not
+    send to the kernel, because their jobs also hold bypass rows."""
+    if engine != "pallas":
+        return 0
+    return int(sum(arrays[i].eligible.sum() for i in idxs))
+
+
 def _group_arrays(idxs: List[int], arrays: List[_JobArrays]):
     """Concatenate one chunk's per-job arrays + per-row hw params."""
     counts = [arrays[i].factors.shape[0] for i in idxs]
@@ -197,7 +206,7 @@ def _dispatch_shards(sig, key: str, factors, rank, store, params,
         m = hi - lo
         f, r, s, p = _pad_rows(factors[lo:hi], rank[lo:hi], store[lo:hi],
                                {k: v[lo:hi] for k, v in params.items()})
-        note_batch_dispatch(sig, f.shape[0], dev)
+        note_batch_dispatch(f.shape[0])
         with device_scope(dev):
             res = evaluate_batch_multi(sig, {k: jnp.asarray(v)
                                              for k, v in p.items()},
@@ -262,16 +271,16 @@ def fused_best(jobs: Sequence[MapspaceJob], goal: str = "edp",
             with tr.span("fused.kernel-group", jobs=len(chunk),
                          rows=rows):
                 _kernel_group(chunk, jobs, arrays, goal, out)
-            tr.metrics.histogram("fused.group_rows").observe(rows)
-            tr.metrics.histogram("fused.group_jobs").observe(len(chunk))
 
     for sig, idxs in groups.items():
         for chunk in _chunk(idxs, sizes, max_group):
             rows = sum(sizes[i] for i in chunk)
-            with tr.span("fused.jnp-group", jobs=len(chunk), rows=rows):
+            with tr.span("fused.jnp-group", jobs=len(chunk),
+                         rows=rows) as sp:
+                if tr.enabled:
+                    sp.set(eligible_rows=_eligible_rows(chunk, arrays,
+                                                        engine))
                 _eval_group(sig, chunk, jobs, arrays, key, out)
-            tr.metrics.histogram("fused.group_rows").observe(rows)
-            tr.metrics.histogram("fused.group_jobs").observe(len(chunk))
     return [b for b in out if b is not None]
 
 
@@ -325,22 +334,21 @@ def fused_launch(jobs: Sequence[MapspaceJob], goal: str = "edp",
             with tr.span("fused.kernel-group", jobs=len(chunk),
                          rows=rows):
                 _kernel_group(chunk, jobs, arrays, goal, out)
-            tr.metrics.histogram("fused.group_rows").observe(rows)
-            tr.metrics.histogram("fused.group_jobs").observe(len(chunk))
 
     pending: List[_PendingGroup] = []
     for sig, idxs in groups.items():
         for chunk in _chunk(idxs, sizes, max_group):
             rows = sum(sizes[i] for i in chunk)
             with tr.span("fused.jnp-dispatch", jobs=len(chunk),
-                         rows=rows):
+                         rows=rows) as sp:
+                if tr.enabled:
+                    sp.set(eligible_rows=_eligible_rows(chunk, arrays,
+                                                        engine))
                 counts, factors, rank, store, params = \
                     _group_arrays(chunk, arrays)
                 plan = _shard_plan(factors.shape[0])
                 pend = _dispatch_shards(sig, key, factors, rank, store,
                                         params, plan)
-            tr.metrics.histogram("fused.group_rows").observe(rows)
-            tr.metrics.histogram("fused.group_jobs").observe(len(chunk))
             pending.append(_PendingGroup(idxs=chunk, counts=counts,
                                          pend=pend))
     return PendingFused(jobs=jobs, key=key, groups=pending, out=out)
@@ -369,6 +377,7 @@ def _kernel_group(idxs: List[int], jobs, arrays: List[_JobArrays],
     from ..core.backend import (default_interpret, device_scope,
                                 validity_mask_arrays)
     from ..kernels.mapspace_eval import ops as _kernel_ops
+    from ..obs import current_tracer
 
     counts = [arrays[i].factors.shape[0] for i in idxs]
     interpret = default_interpret()
@@ -379,26 +388,27 @@ def _kernel_group(idxs: List[int], jobs, arrays: List[_JobArrays],
             cycles, energy = _kernel_ops.mapspace_eval_multi(
                 [(arrays[i].st, arrays[i].factors, arrays[i].rank)
                  for i in sub], interpret=interpret)
-        cyc_parts.append(np.asarray(cycles, np.float64))
-        en_parts.append(np.asarray(energy, np.float64))
-    cycles = np.concatenate(cyc_parts)
-    energy = np.concatenate(en_parts)
-    if goal == "latency":
-        scores = cycles
-    elif goal == "energy":
-        scores = energy
-    else:
-        scores = cycles * energy
-    off = 0
-    for i, cnt in zip(idxs, counts):
-        seg = scores[off: off + cnt].copy()
-        valid = validity_mask_arrays(arrays[i].st, arrays[i].factors,
-                                     arrays[i].store)
-        seg[~valid] = np.inf
-        best = int(np.argmin(seg))
-        out[i] = JobBest(tag=jobs[i].tag, index=best,
-                         value=float(seg[best]), n_scored=cnt)
-        off += cnt
+        cyc_parts.append(cycles)
+        en_parts.append(energy)
+    with current_tracer().span("kernel.select", rows=sum(counts)):
+        cycles = np.concatenate(cyc_parts).astype(np.float64)
+        energy = np.concatenate(en_parts).astype(np.float64)
+        if goal == "latency":
+            scores = cycles
+        elif goal == "energy":
+            scores = energy
+        else:
+            scores = cycles * energy
+        off = 0
+        for i, cnt in zip(idxs, counts):
+            seg = scores[off: off + cnt].copy()
+            valid = validity_mask_arrays(arrays[i].st, arrays[i].factors,
+                                         arrays[i].store)
+            seg[~valid] = np.inf
+            best = int(np.argmin(seg))
+            out[i] = JobBest(tag=jobs[i].tag, index=best,
+                             value=float(seg[best]), n_scored=cnt)
+            off += cnt
 
 
 def _kernel_shard_plan(idxs: List[int], counts: List[int],
@@ -443,7 +453,7 @@ def _eval_group(sig, idxs: List[int], jobs, arrays: List[_JobArrays],
     else:
         factors, rank, store, params = _pad_rows(factors, rank, store,
                                                  params)
-        note_batch_dispatch(sig, factors.shape[0])
+        note_batch_dispatch(factors.shape[0])
         res = evaluate_batch_multi(sig, {k: jnp.asarray(v)
                                          for k, v in params.items()},
                                    jnp.asarray(factors), jnp.asarray(rank),

@@ -34,7 +34,7 @@ from ..core.scheduler import MixDesc, MixResult, schedule_network
 from ..core.task_analyst import TaskDescription, TaskWorkloads, analyze
 from ..core.workload import TENSORS
 from ..obs import (MANIFEST_DIR, ConsoleSink, ProgressStream, activate,
-                   as_stream, as_tracer, build_manifest)
+                   as_stream, as_tracer, build_manifest, jit_summary)
 from .batch_frontier import (MapspaceJob, fused_best, fused_collect,
                              fused_launch, per_arch_best)
 from .cache import (ResultCache, cache_key, decode_result, encode_result,
@@ -95,6 +95,10 @@ class SearchReport:
     wall_time_s: float = 0.0
     cache_stats: Optional[Dict[str, int]] = None
     phase_times: Dict[str, float] = dataclasses.field(default_factory=dict)
+    # the same seconds by thread role: {"main": {...}, "prefetch": {...}};
+    # the main thread's phases never sum past the wall time
+    phase_times_by_thread: Dict[str, Dict[str, float]] = \
+        dataclasses.field(default_factory=dict)
     tracer: Any = None                   # Tracer when tracing was on
     manifest: Any = None                 # RunManifest (cache-backed runs)
     manifest_path: Optional[str] = None
@@ -172,16 +176,11 @@ class SearchReport:
             # seconds by driver phase (empty without an active tracer);
             # matches the phase-flagged spans of the exported trace
             "phase_times": self.phase_times,
+            "phase_times_by_thread": self.phase_times_by_thread,
             "metrics": snap,
-            # jit-compile visibility: per-BatchSig compile counters and
-            # the bucket-size histogram (`batch_eval.note_batch_dispatch`)
-            "jit": ({"counters": {k: v
-                                  for k, v in snap["counters"].items()
-                                  if k.startswith("jit.")},
-                     "histograms": {k: v
-                                    for k, v in
-                                    snap["histograms"].items()
-                                    if k.startswith("jit.")}}
+            # JAX's trace/lower/compile events and compile-cache traffic
+            # (`obs.jax_events`), with the fused scorer's dispatches
+            "jit": (jit_summary(self.tracer.buffer.snapshot(), snap)
                     if snap is not None else None),
             "pareto_size": len(self.pareto),
             "pareto": self.pareto.summary(),
@@ -292,15 +291,17 @@ class _Evaluator:
         if self.packed:
             pm = build_packed_mapspace(wl, hw, self.cfg)
             plan.n_packed_builds += 1
-            k = cache_key(wl, hw, self.cfg, self.goal,
-                          scorer=self.batching, backend=self.backend,
-                          mapspace=pm.digest(),
-                          constraints=self._cdigest, mix=mix)
+            with self.tracer.span("cache-key", phase=True):
+                k = cache_key(wl, hw, self.cfg, self.goal,
+                              scorer=self.batching, backend=self.backend,
+                              mapspace=pm.digest(),
+                              constraints=self._cdigest, mix=mix)
         else:
             pm = None
-            k = cache_key(wl, hw, self.cfg, self.goal,
-                          scorer=self.batching, backend=self.backend,
-                          constraints=self._cdigest, mix=mix)
+            with self.tracer.span("cache-key", phase=True):
+                k = cache_key(wl, hw, self.cfg, self.goal,
+                              scorer=self.batching, backend=self.backend,
+                              constraints=self._cdigest, mix=mix)
         memo[wk] = (pm, k)
         return pm, k
 
@@ -637,11 +638,13 @@ def run_search(task: Union[TaskDescription, TaskWorkloads],
                  records into a fresh `Tracer` (returned as
                  `report.tracer`), False forces tracing off, or pass a
                  `Tracer`.  Spans are host-side only; per-round phases
-                 (propose / static-filter / pack / validate / score /
-                 cache-get / cache-put / assemble / frontier-update,
-                 plus prefetch-build / device-wait / cache-flush under
-                 streaming) land in `report.phase_times` and the
-                 Chrome/JSONL exports.  The default is zero-overhead.
+                 (propose / static-filter / pack / validate / cache-key /
+                 score / cache-get / cache-put / assemble /
+                 frontier-update, plus prefetch-build / device-wait /
+                 cache-flush under streaming) land in
+                 `report.phase_times`, split by thread role in
+                 `report.phase_times_by_thread`, and in the Chrome/JSONL
+                 exports.  The default is zero-overhead.
     progress   : a ProgressStream, sink callable, or list of sinks fed
                  typed `ProgressEvent`s (arch evaluated/skipped, cache
                  lookups, frontier growth, round completion) — the
@@ -905,12 +908,13 @@ def run_search(task: Union[TaskDescription, TaskWorkloads],
 
     # the tracer becomes ambient for the whole search, so instrumented
     # library code (mapper, backend, batch_frontier, cache) records into
-    # it without parameter plumbing; all spans are host-side only
-    with activate(tracer), tracer.span("run_search", strategy=strat.name,
-                                       backend=backend, goal=goal,
-                                       budget=budget,
-                                       space_size=space.size,
-                                       overlap=use_stream):
+    # it without parameter plumbing; all spans are host-side only.  This
+    # thread's spans carry the role "main", the builder thread's
+    # "prefetch" (`report.phase_times_by_thread`)
+    with activate(tracer, role="main"), \
+            tracer.span("run_search", strategy=strat.name,
+                        backend=backend, goal=goal, budget=budget,
+                        space_size=space.size, overlap=use_stream):
         if writer_on:
             cache.start_async_writes()
         try:
@@ -931,7 +935,7 @@ def run_search(task: Union[TaskDescription, TaskWorkloads],
                     # contextvars do not cross threads: re-activate the
                     # ambient tracer so pack/validate/cache-get spans
                     # from the worker land in the same buffer
-                    with activate(tracer):
+                    with activate(tracer, role="prefetch"):
                         return evaluate.prepare(batch)
 
                 pool = concurrent.futures.ThreadPoolExecutor(
@@ -1002,6 +1006,7 @@ def run_search(task: Union[TaskDescription, TaskWorkloads],
     report.wall_time_s = time.perf_counter() - t_begin
     if tracer.enabled:
         report.phase_times = tracer.phase_times()
+        report.phase_times_by_thread = tracer.buffer.phase_times_by_role()
         tracer.metrics.counter("search.rounds").inc(n_rounds)
     if best is None:
         if report.cancelled:
@@ -1029,7 +1034,4 @@ def run_search(task: Union[TaskDescription, TaskWorkloads],
             report, space, wall_time_s=report.wall_time_s, tracer=tracer)
         report.manifest_path = report.manifest.write(
             os.path.join(cache.path, MANIFEST_DIR))
-    elif tracer.enabled:
-        report.manifest = build_manifest(
-            report, space, wall_time_s=report.wall_time_s, tracer=tracer)
     return report

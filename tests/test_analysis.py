@@ -601,8 +601,12 @@ def test_mutation_payload_key_without_bump_fires_r_cache(tmp_path):
 def test_mutation_span_stripped_fires_r_sync(tmp_path):
     root = _copy_repo(tmp_path)
     _mutate(root, "src/repro/search/batch_frontier.py",
-            "            with tr.span(\"fused.jnp-group\", jobs=len(chunk)"
-            ", rows=rows):\n"
+            "            with tr.span(\"fused.jnp-group\", jobs=len(chunk),\n"
+            "                         rows=rows) as sp:\n"
+            "                if tr.enabled:\n"
+            "                    sp.set(eligible_rows=_eligible_rows(chunk, "
+            "arrays,\n"
+            "                                                        engine))\n"
             "                _eval_group(sig, chunk, jobs, arrays, key, "
             "out)",
             "            _eval_group(sig, chunk, jobs, arrays, key, out)")

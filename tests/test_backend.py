@@ -141,6 +141,28 @@ def test_fused_best_pallas_routes_eligible_jobs():
     assert [b.index for b in got] == [b.index for b in ref]
 
 
+def test_kernel_group_spans_split_the_work():
+    from repro.obs import Tracer, activate
+    jobs = _jobs(bypass_second=True)     # job a kernel-eligible, job b not
+    tr = Tracer()
+    with activate(tr):
+        fused_best(jobs, "edp", backend="pallas")
+    spans = tr.buffer.snapshot()
+    group = next(s for s in spans if s.name == "fused.kernel-group")
+    kids = [s for s in spans if s.parent == group.index]
+    assert [s.name for s in kids] == ["kernel.pack", "kernel.transfer",
+                                      "kernel.launch", "kernel.fetch",
+                                      "kernel.select"]
+    assert all(s.attrs["rows"] == group.attrs["rows"] == 70 for s in kids)
+    assert kids[1].attrs["bytes"] > 0
+    assert all(group.t0 <= s.t0 <= s.t1 <= group.t1 for s in kids)
+    # the jnp chunk counts the no-bypass rows the kernel could not take
+    jnp_group = next(s for s in spans if s.name == "fused.jnp-group")
+    n_elig = sum(all(not b for b in m.bypass) for m in jobs[1].mappings)
+    assert 0 < n_elig < 70
+    assert jnp_group.attrs["eligible_rows"] == n_elig
+
+
 def test_per_arch_best_backend_param():
     jobs = _jobs(bypass_second=False)
     ref = per_arch_best(jobs, "edp", backend="jnp")

@@ -369,3 +369,19 @@ def test_two_result_caches_one_directory(tmp_path):
     for k in keys:
         assert c3.get(k) is not None
     assert not (tmp_path / GC_LOCK).exists()
+
+
+def test_pack_phase_splits_into_build_steps():
+    from repro.obs import Tracer, activate
+    cfg = MapperConfig(max_mappings=300, seed=1)
+    tr = Tracer()
+    with activate(tr):
+        pm = build_packed_mapspace(TW.intra[2], HW, cfg)
+    spans = tr.buffer.snapshot()
+    pack_ = next(s for s in spans if s.name == "pack")
+    kids = [s for s in spans if s.parent == pack_.index]
+    assert [s.name for s in kids] == ["pack.tables", "pack.rows",
+                                      "pack.assemble"]
+    assert kids[1].attrs["rows"] == kids[2].attrs["rows"] \
+        == pack_.attrs["candidates"] >= len(pm)
+    assert sum(s.duration for s in kids) <= pack_.duration

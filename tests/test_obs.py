@@ -83,16 +83,17 @@ def test_jsonl_round_trip(tmp_path):
     with tr.span("a", phase=True, k="v"):
         with tr.span("a.b"):
             pass
-    tr.count("hits", 2)
-    tr.count("hits", 1)
+    tr.metrics.counter("hits").inc(2)
+    tr.metrics.counter("hits").inc(1)
     path = tr.export_jsonl(str(tmp_path / "t.jsonl"))
     text = open(path).read()
     lines = [json.loads(l) for l in text.splitlines()]
     assert "meta" in lines[0] and lines[0]["meta"]["n_spans"] == 2
-    assert "counters" in lines[-1]
+    # the last line is the tracer's Metrics snapshot
+    assert lines[-1]["metrics"] == tr.metrics.snapshot()
+    assert lines[-1]["metrics"]["counters"] == {"hits": 3}
     buf2 = TraceBuffer.from_jsonl(text)
     assert len(buf2.snapshot()) == 2
-    assert buf2.counters == {"hits": 3}
     assert buf2.phase_times() == tr.phase_times()
     s0, s1 = buf2.snapshot()
     assert s0.name == "a" and s0.phase and s0.attrs == {"k": "v"}
@@ -104,7 +105,7 @@ def test_chrome_trace_schema(tmp_path):
     with tr.span("score", phase=True, rows=4):
         with tr.span("backend.jnp"):
             pass
-    tr.count("cache.hits", 5)
+    tr.metrics.counter("cache.hits").inc(5)
     path = tr.export_chrome(str(tmp_path / "t.json"))
     with open(path) as f:
         ct = json.load(f)
@@ -126,6 +127,81 @@ def test_chrome_trace_schema(tmp_path):
     assert by["score"]["tid"] != by["backend.jnp"]["tid"]
     assert cs and cs[0]["name"] == "cache.hits" \
         and cs[0]["args"]["value"] == 5
+    # counters are stamped at the buffer's last span end, not at export
+    end = max(e["ts"] + e["dur"] for e in xs)
+    assert cs[0]["ts"] == pytest.approx(end, abs=1e-3)
+
+
+def test_record_nests_finished_intervals():
+    tr = Tracer()
+    w0 = tr.buffer.t_wall0
+    with tr.span("outer"):
+        inner = tr.record("jit.trace", w0 + 0.2, w0 + 0.3, fun="inner")
+        outer = tr.record("jit.trace", w0 + 0.1, w0 + 0.5, fun="outer")
+        after = tr.record("jit.lower", w0 + 0.6, w0 + 0.7, fun="outer")
+    spans = {s.index: s for s in tr.buffer.snapshot()}
+    root = next(s for s in spans.values() if s.name == "outer")
+    # wall-clock times land on the buffer's perf_counter clock
+    assert outer.t0 == pytest.approx(tr.buffer.t_perf0 + 0.1)
+    assert outer.duration == pytest.approx(0.4)
+    # an interval recorded earlier inside a later one becomes its child
+    assert inner.parent == outer.index
+    assert outer.parent == root.index and after.parent == root.index
+
+
+def test_jit_events_become_spans_of_the_open_span():
+    import jax
+    import jax.numpy as jnp
+
+    def obs_fresh_fn(x):
+        return x * 3.0 - 1.0
+
+    f = jax.jit(obs_fresh_fn)
+    x = jnp.arange(8.0)
+    f(x + 1.0)          # NULL_TRACER ambient: the events go nowhere
+    g = jax.jit(lambda x: obs_fresh_fn(x) + 0.5)
+    tr = Tracer()
+    with activate(tr), tr.span("outer"):
+        g(x).block_until_ready()
+    spans = tr.buffer.snapshot()
+    outer = next(s for s in spans if s.name == "outer")
+    mine = [s for s in spans if s.name.startswith("jit.")
+            and s.attrs["fun"] == "<lambda>"]
+    assert sorted(s.name for s in mine) == ["jit.compile", "jit.lower",
+                                           "jit.trace"]
+    assert all(s.parent == outer.index for s in mine)
+    assert all(outer.t0 - 1e-3 <= s.t0 <= s.t1 <= outer.t1 + 1e-3
+               for s in mine)
+    # a second call finds the program in jit's cache: no new events
+    n = len(tr.buffer)
+    with activate(tr), tr.span("again"):
+        g(x).block_until_ready()
+    assert not [s for s in tr.buffer.snapshot()[n:]
+                if s.name.startswith("jit.")]
+
+
+def test_spans_record_the_thread_role():
+    tr = Tracer()
+    with activate(tr, role="main"):
+        with tr.span("pack", phase=True):
+            time.sleep(0.002)
+
+        def work():
+            with activate(tr, role="prefetch"):
+                with tr.span("pack", phase=True):
+                    time.sleep(0.002)
+        t = threading.Thread(target=work)
+        t.start()
+        t.join()
+    with tr.span("score", phase=True):
+        pass
+    by_role = tr.buffer.phase_times_by_role()
+    assert set(by_role) == {"main", "prefetch", "other"}
+    assert set(by_role["main"]) == {"pack"} and by_role["main"]["pack"] > 0
+    assert tr.phase_times()["pack"] == pytest.approx(
+        by_role["main"]["pack"] + by_role["prefetch"]["pack"])
+    assert [s.role for s in tr.buffer.snapshot()] == ["main", "prefetch",
+                                                       None]
 
 
 def test_null_tracer_and_as_tracer():

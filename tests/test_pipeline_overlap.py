@@ -1,14 +1,14 @@
 """Streaming DSE pipeline tests: bit-identical results vs the sequential
 loop, lookahead degradation rules, multi-device shard planning, async
 cache writeback, and jit-compile visibility."""
+import jax
 import numpy as np
 import pytest
 
 from repro.core import (Conv2D, FC, MapperConfig, Pool2D, TaskDescription,
                         Workload, build_mapspace, generate_arch_space,
                         make_spatial_arch)
-from repro.core.batch_eval import (SHARD_MIN_ROWS, reset_jit_registry,
-                                   shard_bounds)
+from repro.core.batch_eval import SHARD_MIN_ROWS, shard_bounds
 from repro.search import (MapspaceJob, ResultCache, fused_best, run_search)
 from repro.search.cache import CACHE_FORMAT
 from repro.search import batch_frontier as bf
@@ -252,7 +252,7 @@ def test_auto_round_size_scales_with_devices():
 # observability: new phases + jit-compile visibility
 # ---------------------------------------------------------------------------
 def test_streaming_trace_has_pipeline_phases(tmp_path):
-    reset_jit_registry()
+    jax.clear_caches()                  # cold start: every program compiles
     rep = run_search(TASK, arch_list(), goal="edp", cfg=CFG, trace=True,
                      overlap=True, round_size=1,
                      cache=str(tmp_path / "c"))
@@ -267,26 +267,48 @@ def test_streaming_trace_has_pipeline_phases(tmp_path):
     assert score and all(s.attrs.get("deferred") for s in score)
 
     jit = rep.summary()["jit"]
-    assert jit["counters"]["jit.dispatches"] >= 1
-    assert jit["counters"]["jit.compiles"] >= 1
-    assert any(k.startswith("jit.compiles[") for k in jit["counters"])
-    hist = jit["histograms"]["jit.bucket_rows"]
-    assert hist["count"] == jit["counters"]["jit.dispatches"]
+    assert jit["dispatches"] >= 1
+    # JAX's own events: the fused scorer was traced, lowered and compiled
+    fused = jit["by_fun"]["evaluate_batch_multi"]
+    assert fused["trace"]["n"] >= 1 and fused["lower"]["n"] >= 1
+    assert fused["compile"]["n"] >= 1
+    assert jit["compile"]["n"] >= fused["compile"]["n"]
+    assert jit["compile"]["s"] > 0
+    hist = jit["bucket_rows"]
+    assert hist["count"] == jit["dispatches"]
     # bucket padding: every dispatched row count is a power of two
     assert float(hist["max"]) == 2 ** int(np.log2(hist["max"]))
 
 
 def test_jit_registry_dedups_recompiles():
-    reset_jit_registry()
+    jax.clear_caches()
     r1 = run_search(TASK, arch_list(), goal="edp", cfg=CFG, trace=True,
                     round_size=1)
     r2 = run_search(TASK, arch_list(), goal="edp", cfg=CFG, trace=True,
                     round_size=1)
     j1, j2 = r1.summary()["jit"], r2.summary()["jit"]
-    assert j1["counters"]["jit.compiles"] >= 1
-    # second run reuses every (sig, bucket, device) executable
-    assert "jit.compiles" not in j2["counters"]
-    assert j2["counters"]["jit.dispatches"] >= 1
+    assert j1["by_fun"]["evaluate_batch_multi"]["compile"]["n"] >= 1
+    # the second run reuses every executable jit's cache holds: nothing
+    # is traced, lowered or compiled again
+    assert "evaluate_batch_multi" not in j2["by_fun"]
+    assert j2["compile"]["n"] == 0 and j2["trace"]["n"] == 0
+    assert j2["dispatches"] >= 1
+
+
+def test_streaming_main_thread_phases_fit_the_wall_time():
+    rep = run_search(TASK, arch_list(), goal="edp", cfg=CFG, trace=True,
+                     overlap=True, round_size=1)
+    assert rep.overlap
+    by = rep.phase_times_by_thread
+    assert {"main", "prefetch"} <= set(by)
+    assert "pack" in by["prefetch"] and "prefetch-build" in by["main"]
+    # the main thread's phases never overlap: their sum fits the wall
+    assert sum(by["main"].values()) <= rep.wall_time_s
+    # phase_times stays the sum over threads
+    for name, v in rep.phase_times.items():
+        assert v == pytest.approx(sum(d.get(name, 0.0)
+                                      for d in by.values()))
+    assert rep.summary()["phase_times_by_thread"] == by
 
 
 def test_summary_jit_absent_without_trace():
