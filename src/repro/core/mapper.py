@@ -206,127 +206,175 @@ def enumerate_index_rows(tables: MapspaceTables):
 
 
 def sample_index_rows(tables: MapspaceTables, cfg: MapperConfig,
-                      seed: int):
+                      seed: int, stats: Optional[Dict[str, int]] = None):
     """Deduplicated candidate sampling as vectorized index arrays.
 
     Draws whole batches with a numpy PCG64 generator (deterministic given
     `seed`): the spatial-bias split, the greedy fan-out fill (random dim
     order per row, budget-constrained spatial divisor per dim, biased
     0.7 towards the largest usable one) and the uniform order/bypass
-    picks are all batched array ops; only first-occurrence dedup walks
-    rows.  Sampling semantics match the seed constructor's `sample_one`
-    (same bias structure and distributions); the draw stream itself is
-    the vectorized generator's.
+    picks.  All of it is whole-batch array work: the fill takes one pass
+    per position in the dim order through lookup tables indexed by
+    (dim, budget), and the first-occurrence dedup keys each row by one
+    mixed-radix int64 (by the row's bytes where the radices overflow
+    int64).  Sampling semantics match the seed constructor's
+    `sample_one` (same bias structure and distributions); the draw
+    stream itself is the vectorized generator's.  `stats`, when given,
+    receives `drawn` (rows drawn) and `batches` (draw rounds).
     """
     import numpy as np
     T = tables
     rng = np.random.default_rng(seed)
-    nd = np.asarray([len(o) for o in T.per_dim], np.int64)
-    # spatial-option lookup per dim: sorted spatial keys, option indices
-    # grouped by key (flat + offsets)
-    sk, flat, off = [], [], []
+    nd = [len(o) for o in T.per_dim]
+    # lookup tables over (dim, v) for v in 0..fanout, flattened to
+    # d * V + v: how many spatial keys are <= v (the greedy pick's range)
+    # and, for a key v, where its option indices start in `flat` and
+    # how many there are; sorted spatial keys padded to [7, W]
+    V = T.first_fanout + 1
+    keys = [sorted(T.by_spatial_idx[d]) for d in range(7)]
+    W = max(len(k) for k in keys)
+    sk = np.zeros((7, W), np.int64)
+    cnt_tab = np.zeros((7, V), np.int64)
+    lo_tab = np.zeros((7, V), np.int64)
+    span_tab = np.zeros((7, V), np.int64)
+    flat = []
     for d in range(7):
-        keys = sorted(T.by_spatial_idx[d])
-        sk.append(np.asarray(keys, np.int64))
-        groups = [T.by_spatial_idx[d][s] for s in keys]
-        flat.append(np.asarray(sum(groups, []), np.int64))
-        off.append(np.concatenate(
-            [[0], np.cumsum([len(g) for g in groups])]).astype(np.int64))
+        sk[d, :len(keys[d])] = keys[d]
+        cnt_tab[d] = np.searchsorted(keys[d], np.arange(V), side="right")
+        for s in keys[d]:
+            lo_tab[d, s] = len(flat)
+            span_tab[d, s] = len(T.by_spatial_idx[d][s])
+            flat.extend(T.by_spatial_idx[d][s])
+    cnt_tab, lo_tab, span_tab = (t.ravel() for t in (cnt_tab, lo_tab,
+                                                      span_tab))
+    sk = sk.ravel()
+    flat = np.asarray(flat, np.int64)
+    row_of_dim = (np.arange(7) * V)[:, None]
     mem = set(T.mem_idx)
-    canon = np.asarray(T.canon_order, np.int64)
 
     def draw(M: int):
-        # -- greedy spatial fill (vectorized over rows) -------------------
+        """-> fi [7, M], oi [L, M], bi [L, M] (dim- and level-major)."""
+        # -- greedy spatial fill, one pass per position k in the dim
+        #    order: each live row fills dim perm[row, k] ----------------
         if T.first_rout is not None:
             greedy = rng.random(M) < cfg.spatial_bias
         else:
             greedy = np.zeros((M,), bool)
-        chosen = np.ones((M, 7), np.int64)
+        chosen = np.ones((7, M), np.int64)          # spatial factor
         if greedy.any():
-            perm = np.argsort(rng.random((M, 7)), axis=1)      # dim order
+            perm = np.argsort(rng.random((M, 7)), axis=1).T.copy()
             budget = np.full((M,), T.first_fanout, np.int64)
+            live = np.flatnonzero(greedy)
             for k in range(7):
                 big = rng.random(M) < 0.7
                 u = rng.random(M)
-                for d in range(7):
-                    rows = greedy & (perm[:, k] == d) & (budget > 1)
-                    if not rows.any():
-                        continue
-                    cnt = np.searchsorted(sk[d], budget[rows], side="right")
-                    pick_i = np.where(big[rows], cnt - 1,
-                                      (u[rows] * cnt).astype(np.int64))
-                    s = sk[d][pick_i]
-                    chosen[rows, d] = s
-                    budget[rows] //= s
-        # -- factor-option index per dim ----------------------------------
-        fi = np.empty((M, 7), np.int64)
+                live = live[budget[live] > 1]
+                d = perm[k, live]
+                b = budget[live]
+                cnt = cnt_tab[d * V + b]
+                pick_i = np.where(big[live], cnt - 1,
+                                  (u[live] * cnt).astype(np.int64))
+                s = sk[d * W + pick_i]
+                chosen[d, live] = s
+                budget[live] = b // s
+        # -- factor-option index per dim: uniform, or uniform among the
+        #    options with the chosen spatial factor ----------------------
+        at = chosen + row_of_dim
+        pos, span = lo_tab[at], span_tab[at]
+        uni = np.empty((7, M), np.int64)
         for d in range(7):
-            uni = rng.integers(0, nd[d], M)
-            j = np.searchsorted(sk[d], chosen[:, d])
-            span = off[d][j + 1] - off[d][j]
-            g = flat[d][off[d][j] + rng.integers(0, span)]
-            fi[:, d] = np.where(greedy, g, uni)
+            uni[d] = rng.integers(0, nd[d], M)
+            pos[d] += rng.integers(0, span[d])
+        fi = np.where(greedy, flat[pos], uni)
         # -- order / bypass indices ---------------------------------------
-        oi = np.full((M, T.nl), -1, np.int64)
+        oi = np.full((T.nl, M), -1, np.int64)
         for li in range(T.nl):
             if li in mem:
-                oi[:, li] = rng.integers(0, len(T.orders), M)
-        bi = np.zeros((M, T.nl), np.int64)
+                oi[li] = rng.integers(0, len(T.orders), M)
+        bi = np.zeros((T.nl, M), np.int64)
         for li in range(T.nl):
             nb = len(T.bypass_choices[li])
             if nb > 1:
-                bi[:, li] = rng.integers(0, nb, M)
+                bi[li] = rng.integers(0, nb, M)
         return fi, oi, bi
 
-    seen = set()
+    # dedup key digits: factor option per dim, canonical order + 1 per
+    # level (0 on a routing level), bypass choice per level
+    radices = nd + [len(T.orders) + 1] * T.nl \
+        + [len(b) for b in T.bypass_choices]
+    int_key = math.prod(radices) <= np.iinfo(np.int64).max
+    canon1 = np.asarray(T.canon_order, np.int64) + 1
+
+    def row_keys(fi, oi, bi):
+        digits = np.concatenate(
+            [fi, np.where(oi >= 0, canon1[oi], 0), bi])
+        if int_key:                                 # mixed radix
+            key = np.zeros(digits.shape[1], np.int64)
+            for r, col in zip(radices, digits):
+                key *= r
+                key += col
+            return key
+        rows = np.ascontiguousarray(digits.T)
+        return rows.view(np.dtype((np.void, rows.shape[1] * 8)))[:, 0]
+
+    kept = []
     out_f, out_o, out_b = [], [], []
     n_out = 0
     drawn = 0
+    batches = 0
     max_draws = 20 * cfg.max_mappings
     while n_out < cfg.max_mappings and drawn < max_draws:
         M = min(max(2 * (cfg.max_mappings - n_out), 1024),
                 max_draws - drawn)
         drawn += M
+        batches += 1
         fi, oi, bi = draw(M)
-        key = np.ascontiguousarray(
-            np.concatenate([fi, np.where(oi >= 0, canon[oi], -1), bi],
-                           axis=1))
-        kb = key.view(np.uint8).reshape(M, -1)
-        take = []
-        for r in range(M):
-            k = kb[r].tobytes()
-            if k not in seen:
-                seen.add(k)
-                take.append(r)
-                n_out += 1
-                if n_out >= cfg.max_mappings:
-                    break
-        take = np.asarray(take, np.int64)
-        out_f.append(fi[take])
-        out_o.append(oi[take])
-        out_b.append(bi[take])
-    fi = np.concatenate(out_f) if out_f else np.empty((0, 7), np.int64)
-    oi = np.concatenate(out_o) if out_o else np.empty((0, T.nl), np.int64)
-    bi = np.concatenate(out_b) if out_b else np.empty((0, T.nl), np.int64)
-    return (fi.astype(np.int32), oi.astype(np.int32), bi.astype(np.int32))
+        key = row_keys(fi, oi, bi)
+        # first occurrence of each key: the least row index of its run
+        # in key order, kept in row order
+        order = np.argsort(key)
+        ks = key[order]
+        starts = np.flatnonzero(np.r_[True, ks[1:] != ks[:-1]])
+        first = np.zeros(M, bool)
+        first[np.minimum.reduceat(order, starts)] = True
+        take = np.flatnonzero(first)
+        if kept:
+            take = take[~np.isin(key[take], np.concatenate(kept))]
+        take = take[:cfg.max_mappings - n_out]
+        kept.append(key[take])
+        n_out += len(take)
+        out_f.append(fi[:, take])
+        out_o.append(oi[:, take])
+        out_b.append(bi[:, take])
+    if stats is not None:
+        stats.update(drawn=drawn, batches=batches)
+
+    def rows(parts, n):                 # [n, B] pieces -> [B, n] int32
+        if not parts:
+            return np.empty((0, n), np.int32)
+        return np.ascontiguousarray(np.concatenate(parts, axis=1).T,
+                                    np.int32)
+    return rows(out_f, 7), rows(out_o, T.nl), rows(out_b, T.nl)
 
 
 def candidate_index_rows(workload: Workload, hw: HardwareDesc,
                          cfg: MapperConfig):
     """-> (tables, fi, oi, bi): the full candidate set when it fits the
     budget, the deduplicated vectorized sample otherwise.  Records the
-    `pack.tables` and `pack.rows` spans into the ambient tracer."""
+    `pack.tables` and `pack.rows` spans into the ambient tracer; a sampled
+    `pack.rows` also carries `drawn` and `batches`."""
     from ..obs import current_tracer
     tr = current_tracer()
     rng = random.Random(cfg.seed)
     with tr.span("pack.tables"):
         tables = mapspace_tables(workload, hw, cfg, rng)
     with tr.span("pack.rows", total=tables.total) as sp:
+        stats: Dict[str, int] = {}
         if tables.total <= cfg.max_mappings:
             fi, oi, bi = enumerate_index_rows(tables)
         else:
-            fi, oi, bi = sample_index_rows(tables, cfg, cfg.seed)
-        sp.set(rows=int(fi.shape[0]))
+            fi, oi, bi = sample_index_rows(tables, cfg, cfg.seed, stats)
+        sp.set(rows=int(fi.shape[0]), **stats)
     return tables, fi, oi, bi
 
 

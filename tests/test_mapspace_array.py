@@ -2,8 +2,16 @@
 plumbing: bit-exact parity with the object path (candidate set, validity,
 pruning, survivors, winners), packed scoring through every backend, the
 multi-arch Pallas kernel, fused-frontier kernel grouping (one call per
-BatchSig group), round_size auto-tuning, and the cross-process GC lock."""
+BatchSig group), round_size auto-tuning, the cross-process GC lock, and
+the candidate sampler against its frozen copy in `bench/reference`."""
+import dataclasses
+import functools
+import json
+import math
 import os
+import random
+import sys
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -16,6 +24,7 @@ from repro.core.batch_eval import (batch_best_index, batch_scores,
                                    bucket, evaluate_batch_multi,
                                    make_static, pack, params_of, sig_of)
 from repro.core.backend import score_mapspace, best_index
+from repro.core.mapper import mapspace_tables, sample_index_rows
 from repro.search import (MapspaceJob, ResultCache, cache_key, fused_best,
                           per_arch_best, run_search)
 from repro.search.cache import CACHE_FORMAT, GC_LOCK
@@ -385,3 +394,152 @@ def test_pack_phase_splits_into_build_steps():
     assert kids[1].attrs["rows"] == kids[2].attrs["rows"] \
         == pack_.attrs["candidates"] >= len(pm)
     assert sum(s.duration for s in kids) <= pack_.duration
+
+
+def test_pack_rows_counts_draws():
+    from repro.obs import Tracer, activate
+    cfg = MapperConfig(max_mappings=300, seed=1)
+    tr = Tracer()
+    with activate(tr):
+        build_packed_mapspace(TW.intra[2], HW, cfg)
+    rows = next(s for s in tr.buffer.snapshot() if s.name == "pack.rows")
+    assert rows.attrs["total"] > cfg.max_mappings         # sampled
+    assert rows.attrs["drawn"] >= rows.attrs["rows"] == cfg.max_mappings
+    assert rows.attrs["batches"] >= 1
+
+
+# ---------------------------------------------------------------------------
+# the sampler against its frozen copy in the benchmark's reference
+# ---------------------------------------------------------------------------
+_REPO = Path(__file__).resolve().parents[1]
+_SEEDS = (0, 3000014001, 2 ** 31 + 7)
+
+
+def _reference_mapper():
+    if str(_REPO) not in sys.path:
+        sys.path.insert(0, str(_REPO))
+    from bench.reference.trim import mapper
+    return mapper
+
+
+@functools.lru_cache(maxsize=None)
+def _bench_tables(name, bypass):
+    """A dozen of the 216 distinct mapspaces of one query of a benchmark
+    configuration, spread over its designs and workloads."""
+    from repro.core.task_analyst import NETWORKS
+    config = json.loads((_REPO / "bench" / "configs" / f"{name}.json")
+                        .read_text())
+    net, lat = config["network"], config["lattice"]
+    task = analyze(NETWORKS[net["name"]](batch_size=net["batch_size"],
+                                          processing=net["processing"]))
+    space = ArchSpace.spatial(num_pes=lat["num_pes"],
+                              rf_words=lat["rf_words"],
+                              gbuf_words=lat["gbuf_words"], bits=lat["bits"],
+                              zero_skip=lat["zero_skip"])
+    cfg = MapperConfig(max_mappings=config["max_mappings"],
+                       enable_bypass=bypass)
+    # mapspace tables read only a workload's dims
+    dims = list(dict.fromkeys(w.dims for w in task.intra))
+    pairs = [(space.at(c), d) for c in space.all_coords() for d in dims]
+    return config["max_mappings"], [
+        mapspace_tables(Workload(dims=d), hw, cfg, random.Random(0))
+        for hw, d in pairs[::len(pairs) // 12]]
+
+
+def _no_routing_case():
+    hw = dataclasses.replace(
+        HW, levels=tuple(lv for lv in HW.levels if lv.kind != "routing"))
+    cfg = MapperConfig(max_mappings=500, seed=5)
+    tables = mapspace_tables(Workload(dims=(4, 16, 8, 3, 3, 8, 8)), hw, cfg,
+                             random.Random(5))
+    assert tables.first_rout is None                    # no greedy fill
+    return cfg, [tables], lambda stats: None
+
+
+def _multi_batch_case():
+    # total just above the budget: ~one new row per batch at the end
+    cfg = MapperConfig(max_mappings=10 ** 9, enable_bypass=False, seed=3)
+    tables = mapspace_tables(Workload(dims=(2, 1, 1, 1, 1, 1, 1)), HW, cfg,
+                             random.Random(3))
+    cfg = dataclasses.replace(cfg, max_mappings=tables.total - 1)
+
+    def check(stats):
+        assert stats["batches"] > 1
+    return cfg, [tables], check
+
+
+def _exhausted_case():
+    # every order listed twice (as colliding random orders are): fewer
+    # distinct rows than the budget, so sampling runs out of draws
+    cfg = MapperConfig(max_mappings=10 ** 9, enable_bypass=False, seed=4)
+    tables = mapspace_tables(Workload(dims=(2, 1, 1, 1, 1, 1, 1)), HW, cfg,
+                             random.Random(4))
+    distinct = tables.total
+    tables = dataclasses.replace(
+        tables, orders=tables.orders * 2,
+        canon_order=tables.canon_order * 2,
+        total=tables.total * 2 ** len(tables.mem_idx))
+    cfg = dataclasses.replace(cfg, max_mappings=distinct + 1)
+    assert tables.total > cfg.max_mappings
+
+    def check(stats):
+        assert stats["batches"] > 1
+        assert stats["drawn"] == 20 * cfg.max_mappings
+    return cfg, [tables], check
+
+
+def _int64_overflow_case():
+    # one loop order listed 5040 times (as colliding random orders are):
+    # the key's radices overflow int64, while few distinct rows make
+    # the dedup work
+    cfg = MapperConfig(max_mappings=2000, seed=9)
+    tables = mapspace_tables(Workload(dims=(2, 2, 2, 2, 2, 1, 1)), HW, cfg,
+                             random.Random(9))
+    n, levels = 5040, len(tables.mem_idx)
+    tables = dataclasses.replace(
+        tables, orders=tables.orders[:1] * n, canon_order=[0] * n,
+        total=tables.total // len(tables.orders) ** levels * n ** levels)
+    radices = [len(o) for o in tables.per_dim] \
+        + [len(tables.orders) + 1] * tables.nl \
+        + [len(b) for b in tables.bypass_choices]
+    assert math.prod(radices) > np.iinfo(np.int64).max   # bytes key
+    assert tables.total > cfg.max_mappings
+
+    def check(stats):
+        assert stats["drawn"] > cfg.max_mappings          # duplicates met
+    return cfg, [tables], check
+
+
+def _bench_case(name, bypass, seed):
+    def case():
+        budget, tables = _bench_tables(name, bypass)
+        cfg = MapperConfig(max_mappings=budget, enable_bypass=bypass,
+                           seed=seed)
+        return cfg, tables, lambda stats: None
+    return case
+
+
+SAMPLER_CASES = {
+    f"{name}-{'bypass' if bypass else 'nobypass'}-{seed}":
+        _bench_case(name, bypass, seed)
+    for name in ("alexnet-cifar-fig20", "resnet18-im-fig20")
+    for bypass in (True, False) for seed in _SEEDS}
+SAMPLER_CASES.update({"no-routing-level": _no_routing_case,
+                      "multi-batch": _multi_batch_case,
+                      "exhausts-max-draws": _exhausted_case,
+                      "int64-overflow-bytes-key": _int64_overflow_case})
+
+
+@pytest.mark.parametrize("case", SAMPLER_CASES)
+def test_sample_index_rows_matches_frozen_reference(case):
+    cfg, tables, check = SAMPLER_CASES[case]()
+    reference = _reference_mapper()
+    for t in tables:
+        stats = {}
+        got = sample_index_rows(t, cfg, cfg.seed, stats)
+        want = reference.sample_index_rows(t, cfg, cfg.seed)
+        for g, w in zip(got, want):
+            assert g.dtype == w.dtype
+            np.testing.assert_array_equal(g, w)
+        assert stats["drawn"] >= len(got[0])
+        check(stats)
