@@ -1,25 +1,45 @@
 """One DSE query through the program's public entry, `run_search`, built
-from a configuration and a traffic file; and its answer as plain data for
-the reference."""
+from a configuration and a traffic file; the task it searches for; and
+its answer as plain data for the reference."""
 from __future__ import annotations
 
+import dataclasses
 from typing import Any, Callable, Dict
+
+
+def program_task(config: Dict[str, Any]):
+    """The program's TaskWorkloads for a configuration's `network`: a
+    paper CNN (`name`, `batch_size`, `processing`) through the task
+    analyst, or an LM block (`lm`, a `repro.configs` registry id, with
+    `shape` and the `overrides` the configuration cuts) lowered by
+    `lower_block`, whose workloads are the task's intra-layer ones."""
+    net = config["network"]
+    if "lm" not in net:
+        from repro.core import analyze
+        from repro.core.task_analyst import NETWORKS
+        return analyze(NETWORKS[net["name"]](
+            batch_size=net["batch_size"], processing=net["processing"]))
+    from repro.configs import ShapeSpec, get_config
+    from repro.core.lower_lm import lower_block
+    from repro.core.task_analyst import TaskWorkloads
+    cfg = dataclasses.replace(get_config(net["lm"]),
+                              **net.get("overrides", {}))
+    lowered = lower_block(cfg, ShapeSpec(name=net["name"], **net["shape"]))
+    return TaskWorkloads(intra=lowered.all_workloads(), preproc=[],
+                         activations=[])
 
 
 def make_query(config: Dict[str, Any], traffic: Dict[str, Any],
                seed: int) -> Callable[..., Any]:
-    """-> query(trace) -> SearchReport.  The network is analysed once
-    here; every query gets a fresh lattice and a fresh in-memory result
-    cache (`cache=None`), so only compiled programs carry from one query
-    to the next."""
-    from repro.core import MapperConfig, analyze
-    from repro.core.task_analyst import NETWORKS
+    """-> query(trace) -> SearchReport.  The task is built once here
+    (`program_task`); every query gets a fresh lattice and a fresh
+    in-memory result cache (`cache=None`), so only compiled programs carry
+    from one query to the next."""
+    from repro.core import MapperConfig
     from repro.search import run_search
     from repro.search.space import ArchSpace
 
-    net = config["network"]
-    task = analyze(NETWORKS[net["name"]](batch_size=net["batch_size"],
-                                          processing=net["processing"]))
+    task = program_task(config)
     cfg = MapperConfig(max_mappings=config["max_mappings"], seed=seed,
                        **traffic["mapper"])
     lat = config["lattice"]

@@ -4,7 +4,8 @@ The program's answer to a DSE query is, for every design of the lattice
 and every workload of the network, a winning mapping with its cycles and
 energy; per design the network's cycles, energy and EDP; and the best
 design.  The plain reference (`trim/`, float64, imports nothing of the
-program) judges it:
+program; an LM network's workloads from its own file in `networks/`)
+judges it:
 
   missing        designs or workloads with no answer, or under another name
   invalid        winners the reference's validator refuses, or that
@@ -28,6 +29,7 @@ threads), which import nothing but the reference.
 from __future__ import annotations
 
 import dataclasses
+import importlib.util
 import json
 import math
 import multiprocessing
@@ -62,9 +64,19 @@ def _rel(p: float, r: float) -> float:
 
 
 def reference_task(config: Dict[str, Any]):
+    """The reference's TaskWorkloads for a configuration's `network`: a
+    paper CNN from the frozen task analyst; an LM network from `task
+    (config)` of its reference network file, at the `reference_file` that
+    `bench/cell.py` found under the cell's root."""
     net = config["network"]
-    return analyze(NETWORKS[net["name"]](batch_size=net["batch_size"],
-                                          processing=net["processing"]))
+    if "lm" not in net:
+        return analyze(NETWORKS[net["name"]](batch_size=net["batch_size"],
+                                              processing=net["processing"]))
+    spec = importlib.util.spec_from_file_location(
+        "bench_reference_network", net["reference_file"])
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.task(config)
 
 
 def reference_designs(config: Dict[str, Any]) -> list:

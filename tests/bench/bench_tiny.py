@@ -1,6 +1,7 @@
 """A tiny cell for the benchmark's CPU tests: a root directory holding a
 BENCHMARK.json, a configuration, the traffic files and the metric
-readers, as a checkout would, at a size the CPU scores in a second."""
+readers, as a checkout would, at a size the CPU scores in a second; and,
+where asked, a tiny LM configuration with its reference network file."""
 from __future__ import annotations
 
 import json
@@ -26,11 +27,79 @@ TINY_CONFIG = {
     "max_mappings": 96,
 }
 
+# SmolLM-135M, one layer, training at sequence 32 and batch 2: the program
+# lowers it with `lower_block`, the reference from SMOLLM_REFERENCE.
+TINY_LM_CONFIG = dict(
+    TINY_CONFIG, name="tiny-lm",
+    network={"name": "smollm-135m-tiny", "lm": "smollm-135m",
+             "shape": {"seq_len": 32, "global_batch": 2, "kind": "train"},
+             "overrides": {"n_layers": 1}},
+    reduced={"n_layers": "one of the 30 layers"})
 
-def make_root(tmp: Path, extra_metrics=(), extra_traffic=None) -> Path:
+# The reference network file of TINY_LM_CONFIG, from SmolLM-135M's
+# published config.json (huggingface.co/HuggingFaceTB/SmolLM-135M).
+SMOLLM_REFERENCE = '''"""SmolLM-135M lowered to matrix multiplications:
+one block (GQA attention, causal, and a SwiGLU MLP) per layer and the LM
+head; in training each also as its input-gradient (BW) and
+weight-gradient (WG) product."""
+from bench.reference.trim.task_analyst import TaskWorkloads
+from bench.reference.trim.workload import matmul_workload
+
+HIDDEN = 576
+HEADS = 9
+KV_HEADS = 3
+HEAD_DIM = 64
+FFN = 1536
+VOCAB = 49152
+LAYERS = 30
+
+
+def _phases(mms, training):
+    fw = [matmul_workload(rows=r, cols=c, inner=k, name=n)
+          for n, r, c, k in mms]
+    if not training:
+        return fw
+    grads = []
+    for n, r, c, k in mms:
+        grads.append(matmul_workload(rows=r, cols=k, inner=c,
+                                     name=n + ".BW", phase="BW"))
+        grads.append(matmul_workload(rows=k, cols=c, inner=r,
+                                     name=n + ".WG", phase="WG"))
+    return fw + grads
+
+
+def task(config):
+    net = config["network"]
+    shape = net["shape"]
+    b, s, kind = shape["global_batch"], shape["seq_len"], shape["kind"]
+    layers = net.get("overrides", {}).get("n_layers", LAYERS)
+    sq = 1 if kind == "decode" else s
+    t = b * sq
+    att = b * HEADS * sq if kind == "decode" else b * HEADS * sq // 2
+    block = [("q", t, HEADS * HEAD_DIM, HIDDEN),
+             ("k", t, KV_HEADS * HEAD_DIM, HIDDEN),
+             ("v", t, KV_HEADS * HEAD_DIM, HIDDEN),
+             ("scores", att, s, HEAD_DIM),
+             ("attn_v", att, HEAD_DIM, s),
+             ("o", t, HIDDEN, HEADS * HEAD_DIM),
+             ("mlp_up", t, 2 * FFN, HIDDEN),
+             ("mlp_down", t, HIDDEN, FFN)]
+    training = kind == "train"
+    intra = (_phases(block, training) * layers
+             + _phases([("lm_head", t, VOCAB, HIDDEN)], training))
+    return TaskWorkloads(intra=intra, preproc=[], activations=[])
+'''
+
+
+def make_root(tmp: Path, extra_metrics=(), extra_traffic=None,
+              lm_config=None, lm_reference=None) -> Path:
     """tmp/BENCHMARK.json + tmp/bench/{configs,traffic,metrics}: the
     repository's traffic and metric files, a tiny configuration, and
-    cells `tiny.nobypass` and `tiny.bypass` that report every metric."""
+    cells `tiny.nobypass` and `tiny.bypass` that report every metric.
+    With `lm_config` also that configuration as `tiny-lm`, with cells
+    `tiny-lm.nobypass` and `tiny-lm.bypass`, and with `lm_reference` its
+    reference network file: new files only, as a later configuration
+    brings them."""
     bench = tmp / "bench"
     for d in ("traffic", "metrics"):
         shutil.copytree(REPO / "bench" / d, bench / d, dirs_exist_ok=True)
@@ -48,6 +117,20 @@ def make_root(tmp: Path, extra_metrics=(), extra_traffic=None) -> Path:
     spec["workloads"] = [{"name": f"tiny.{t}", "config": "tiny",
                           "traffic": t, "chips": 1, "why": "test"}
                          for t in traffics]
+    if lm_config is not None:
+        (bench / "configs" / "tiny-lm.json").write_text(
+            json.dumps(lm_config))
+        spec["configs"].append({"name": "tiny-lm", "source": "test",
+                                "file": "bench/configs/tiny-lm.json",
+                                "reduced": [], "why": "test"})
+        spec["workloads"] += [{"name": f"tiny-lm.{t}", "config": "tiny-lm",
+                               "traffic": t, "chips": 1, "why": "test"}
+                              for t in ("nobypass", "bypass")]
+    if lm_reference is not None:
+        networks = bench / "reference" / "networks"
+        networks.mkdir(parents=True, exist_ok=True)
+        (networks / f"{lm_config['network']['name']}.py").write_text(
+            lm_reference)
     for m in spec["per_layer"]:
         m.pop("workloads", None)
     for name, unit, source in extra_metrics:

@@ -36,6 +36,16 @@ def _scaled_energy(evaluate):
     return evaluate_scaled
 
 
+FAULTS = [
+    ("half the rows", "repro.search.driver.build_packed_mapspace",
+     _half_rows, "regret"),
+    ("winner altered", "repro.search.driver.fused_collect",
+     _next_winner, "regret"),
+    ("estimate altered", "repro.search.driver.evaluate_mapping",
+     _scaled_energy, "estimate_gap"),
+]
+
+
 @pytest.fixture
 def root(tmp_path):
     return make_root(tmp_path)
@@ -47,14 +57,7 @@ def test_sound_run_is_correct(root, monkeypatch, capsys):
     assert result["correct"] is True
 
 
-@pytest.mark.parametrize("fault,target,wrap,number", [
-    ("half the rows", "repro.search.driver.build_packed_mapspace",
-     _half_rows, "regret"),
-    ("winner altered", "repro.search.driver.fused_collect",
-     _next_winner, "regret"),
-    ("estimate altered", "repro.search.driver.evaluate_mapping",
-     _scaled_energy, "estimate_gap"),
-])
+@pytest.mark.parametrize("fault,target,wrap,number", FAULTS)
 def test_fault_is_not_correct(root, monkeypatch, capsys, fault, target,
                               wrap, number):
     import importlib
