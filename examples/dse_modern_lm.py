@@ -52,7 +52,7 @@ def lm_task_workloads(top_k=3):
     cfg = get_config("deepseek-v2-lite-16b")
     spec = ShapeSpec("small_train", 512, 8, "train")
     lowered = lower_block(cfg, spec)
-    top = sorted(lowered.workloads, key=lambda w: -w.macs)[:top_k]
+    top = sorted(lowered.block_workloads(), key=lambda w: -w.macs)[:top_k]
     return cfg, TaskWorkloads(intra=top, preproc=[], activations=[])
 
 
@@ -131,11 +131,12 @@ def main():
     cfg = get_config("deepseek-v2-lite-16b")
     spec = ShapeSpec("small_train", 512, 8, "train")  # CPU-sized instance
     lowered = lower_block(cfg, spec)
-    print(f"{cfg.name}: one block lowers to {len(lowered.workloads)} TRIM "
-          f"workloads x {lowered.repeat} layers "
+    print(f"{cfg.name}: {len(lowered.kinds)} kinds of block lower to "
+          f"{len(lowered.block_workloads())} distinct TRIM workloads over "
+          f"{lowered.n_layers} layers "
           f"({lowered.total_macs() / 1e12:.2f} TMACs total)\n")
 
-    top = sorted(lowered.workloads, key=lambda w: -w.macs)[:5]
+    top = sorted(lowered.block_workloads(), key=lambda w: -w.macs)[:5]
     hw = make_spatial_arch(num_pes=1024, rf_words=512,
                            gbuf_words=512 * 1024, bits=16, zero_skip=False)
     mcfg = MapperConfig(max_mappings=1500, seed=0, pe_utilization_min=0.5)

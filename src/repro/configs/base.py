@@ -38,6 +38,7 @@ class ModelConfig:
     first_dense_layers: int = 0      # leading dense layers (DeepSeek: 1)
     d_ff_dense: int = 0              # d_ff of those dense layers
     capacity_factor: float = 1.25
+    experts_held: int = 0            # routed experts on this chip (0 = all)
     # SSM (Mamba2 / SSD)
     d_state: int = 0
     ssm_heads: int = 0

@@ -121,7 +121,8 @@ def plan_cell(cfg: ModelConfig, spec: ShapeSpec, *, data_par: int,
               ) -> Dict[str, PlanChoice]:
     """Plan the dominant workloads of one (arch x shape) cell."""
     lowered = lower_block(cfg, spec)
-    wls = sorted(lowered.workloads, key=lambda w: -w.macs)[:top_workloads]
+    wls = sorted(lowered.block_workloads(),
+                 key=lambda w: -w.macs)[:top_workloads]
     hw = make_tpu_pod_desc(data_par * model_par)
     return {w.name: plan_workload(w, data_par=data_par,
                                   model_par=model_par, hw=hw)[0]
