@@ -32,6 +32,10 @@ them as static Python floats (functools.partial), the multi-arch variant
 reads them from per-row arrays (zsf [L1*3, B], mem_par [Lm*3, B],
 hw_row [4, B]) so rows of any architectures sharing a structural
 BatchSig fuse into one call — the `evaluate_batch_multi` contract.
+The multi-arch call is a module-level `jax.jit` keyed on the chain layout
+(`vis`, `n_mem`), `block` and the bucketed shapes, so each program is
+traced and lowered once per process; the single-arch kernel, whose static
+floats differ per design, is called eagerly.
 """
 from __future__ import annotations
 
@@ -41,6 +45,8 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from ...obs import current_tracer
 
 
 def _row(ref, k: int):
@@ -247,6 +253,20 @@ def mapspace_eval_fwd(bounds, cum, rel_i, rel_w, rel_o, tw_u, tw_p, fresh,
                       block, interpret, "mapspace_eval")
 
 
+@functools.partial(jax.jit,
+                   static_argnames=("vis", "n_mem", "block", "interpret"))
+def _multi_call(*arrays, vis: Tuple[int, ...], n_mem: int, block: int,
+                interpret: bool):
+    """The multi-architecture Pallas call, compiled once per process for
+    each chain layout (`vis`, `n_mem`), `block`, `interpret` and array
+    shapes.  This body runs only while JAX traces it, so `kernel.traces`
+    counts the programs built, not the calls."""
+    current_tracer().metrics.counter("kernel.traces").inc()
+    kern = functools.partial(_score_kernel_multi, vis=vis, n_mem=n_mem)
+    return _lane_call(kern, list(arrays), block, interpret,
+                      "mapspace_eval_multi")
+
+
 def mapspace_eval_multi_fwd(bounds, cum, rel_i, rel_w, rel_o, tw_u, tw_p,
                             fresh, ia, ib, noc_e, noc_m, zsf, mem_par,
                             hw_row, *, static: dict, block: int = 256,
@@ -255,8 +275,7 @@ def mapspace_eval_multi_fwd(bounds, cum, rel_i, rel_w, rel_o, tw_u, tw_p,
     per-row hardware arrays (zsf [L1*3, B], mem_par [Lm*3, B],
     hw_row [4, B]).  All array args share the trailing mapping axis B
     (a multiple of `block`).  Returns (cycles [1, B], energy [1, B])."""
-    kern = functools.partial(_score_kernel_multi, **static)
-    return _lane_call(kern, [bounds, cum, rel_i, rel_w, rel_o, tw_u, tw_p,
-                             fresh, ia, ib, noc_e, noc_m, zsf, mem_par,
-                             hw_row],
-                      block, interpret, "mapspace_eval_multi")
+    return _multi_call(bounds, cum, rel_i, rel_w, rel_o, tw_u, tw_p, fresh,
+                       ia, ib, noc_e, noc_m, zsf, mem_par, hw_row,
+                       vis=static["vis"], n_mem=static["n_mem"],
+                       block=block, interpret=interpret)

@@ -282,8 +282,9 @@ def mapspace_eval_multi(groups: List[Tuple[HwStatic, np.ndarray,
     kernel call for the whole cross-architecture batch.  Each step is a
     span of the ambient tracer: `kernel.pack` (host rows), `kernel.transfer`
     (lane layout and copy to the device), `kernel.launch` (the call up to
-    its return: trace, lowering, program load, enqueue) and `kernel.fetch`
-    (the wait and the copy back)."""
+    its return: the enqueue, plus trace, lowering and program load on a
+    program's first call in the process, counted by `kernel.traces`) and
+    `kernel.fetch` (the wait and the copy back)."""
     tr = current_tracer()
     with tr.span("kernel.pack",
                  rows=sum(int(g[1].shape[0]) for g in groups)):

@@ -13,6 +13,7 @@ from __future__ import annotations
 
 import functools
 import os
+import re
 import time
 
 import jax
@@ -23,7 +24,8 @@ from repro.core import MapperConfig, analyze, make_fpga_arch, make_spatial_arch
 from repro.core.mapspace_array import build_packed_mapspace
 from repro.core.task_analyst import NETWORKS
 from repro.kernels.mapspace_eval import ops
-from repro.kernels.mapspace_eval.kernel import (mapspace_eval_fwd,
+from repro.kernels.mapspace_eval.kernel import (_multi_call,
+                                                mapspace_eval_fwd,
                                                 mapspace_eval_multi_fwd)
 
 BLOCK = 256
@@ -104,3 +106,19 @@ def test_single_kernel_compiles_for_v5e(one_chip, kind, n_rows):
     compiled, secs = _compile(fn, arrays, n_rows, one_chip)
     assert "tpu_custom_call" in compiled.as_text()
     assert secs < 60, f"{kind} B={n_rows}: compile took {secs:.1f}s"
+
+
+@pytest.mark.parametrize("kind", ["spatial", "fpga"])
+def test_cached_multi_entry_compiles_for_v5e(one_chip, kind):
+    """The module-level jitted entry the search calls compiles, and its
+    kernel op keeps the `mapspace_eval` name the device-trace metrics
+    (`kernel_ms`, `mapspace_eval_roofline`) match."""
+    st, factors, rank = _packed(kind)
+    arrays, static, _, block = ops.pack_for_kernel_multi(
+        [(st, factors, rank)], BLOCK)
+    shapes = [jax.ShapeDtypeStruct(a.shape, jnp.float32, sharding=one_chip)
+              for a in arrays]
+    hlo = _multi_call.lower(*shapes, **static, block=block,
+                            interpret=False).compile().as_text()
+    assert "tpu_custom_call" in hlo
+    assert re.search(r"%mapspace_eval\w*(\.\d+)? = ", hlo), hlo[:2000]

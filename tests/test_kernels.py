@@ -125,3 +125,47 @@ def test_mapspace_eval_pads_to_block():
     assert ck.shape == (37,)
     np.testing.assert_allclose(ck, cr, rtol=1e-5)
     np.testing.assert_allclose(ek, er, rtol=1e-4)
+
+
+def _multi_groups():
+    """(HwStatic, factors, rank) groups of the first two `_mapspaces`,
+    with the mappings each group's rows came from."""
+    from repro.core.batch_eval import make_static, pack
+    out = []
+    for _, ms in list(_mapspaces())[:2]:
+        st = make_static(ms[0].hardware, ms[0].workload)
+        factors, rank, _ = pack(ms)
+        out.append(((st, factors, rank), ms))
+    return out
+
+
+@pytest.mark.parametrize("block", [64, 256])
+def test_mapspace_eval_multi_traces_once_per_bucket(block):
+    """The multi-architecture call is built once per (chain layout,
+    bucket, block): a second row count in the same 256-row bucket reuses
+    the program, a larger bucket builds one more.  Every call still
+    matches the oracle."""
+    from repro.kernels.mapspace_eval import kernel
+    from repro.kernels.mapspace_eval.ops import mapspace_eval_multi
+    from repro.kernels.mapspace_eval.ref import mapspace_eval_ref
+    from repro.obs import Tracer, activate
+    (g0, ms0), (g1, ms1) = _multi_groups()
+    calls = [([(g0[0], g0[1][:37], g0[2][:37])], [ms0[:37]]),
+             ([g0], [ms0]),
+             ([g0, g1, g0, g1], [ms0, ms1, ms0, ms1])]
+    assert len(ms0) != 37
+    assert 256 < 2 * (len(ms0) + len(ms1)) <= 512
+    kernel._multi_call.clear_cache()
+    tr = Tracer()
+    traces = []
+    with activate(tr):
+        for groups, mss in calls:
+            ck, ek = mapspace_eval_multi(groups, block=block,
+                                         interpret=True)
+            refs = [mapspace_eval_ref(ms) for ms in mss]
+            np.testing.assert_allclose(
+                ck, np.concatenate([r[0] for r in refs]), rtol=1e-5)
+            np.testing.assert_allclose(
+                ek, np.concatenate([r[1] for r in refs]), rtol=1e-4)
+            traces.append(tr.metrics.snapshot()["counters"]["kernel.traces"])
+    assert traces == [1, 1, 2]
