@@ -15,7 +15,7 @@ contract:
     thread needs, so overlap is zero-sum once XLA saturates them — the
     speedup floor here is only a no-harm bound, and the >=1.25x (fast)
     / >=1.4x (full) speedup claim is enforced when a real accelerator
-    backend is attached (same gating idiom as bench_backend_dispatch);
+    backend is attached;
   * jit visibility: warm arms reuse every (sig, bucket, device)
     executable — `summary()['jit']` shows dispatches but no recompiles.
 

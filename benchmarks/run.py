@@ -25,8 +25,6 @@ MODULES = [
     ("fig16_17_zero_skipping", {"max_mappings": 3000}),
     ("fig18_19_batch_size", {"max_mappings": 3000}),
     ("fig20_21_edp_dse", {"max_mappings": 1500}),
-    ("bench_mapspace_throughput", {"max_mappings": 20000}),
-    ("bench_backend_dispatch", {"max_mappings": 2000}),
     ("bench_search_strategies", {"max_mappings": 800}),
     ("bench_mix_search", {"max_mappings": 1200}),
     ("bench_pipeline_overlap", {"max_mappings": 2000}),

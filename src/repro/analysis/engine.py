@@ -65,7 +65,7 @@ class Module:
     dotted: str                   # "repro.search.cache"
     parents: Dict[ast.AST, ast.AST] = dataclasses.field(default_factory=dict)
     # local name -> fully dotted origin ("jnp" -> "jax.numpy",
-    # "evaluate_batch" -> "repro.core.batch_eval.evaluate_batch")
+    # "evaluate_batch_multi" -> "repro.core.batch_eval.evaluate_batch_multi")
     aliases: Dict[str, str] = dataclasses.field(default_factory=dict)
     # top-level defs: qualname -> node ("cache_key", "ResultCache.get")
     functions: Dict[str, ast.AST] = dataclasses.field(default_factory=dict)

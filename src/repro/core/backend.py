@@ -3,11 +3,13 @@
 `score_mapspace` scores a batch of mappings (all on one hardware/workload
 pair) and routes each mapping to one of two numerically-matched engines:
 
-  * ``jnp``    — `core.batch_eval.evaluate_batch`, the vectorized oracle
-    (validated against the scalar evaluator and the loop simulator);
-  * ``pallas`` — `kernels.mapspace_eval`, the paper's mapping-scoring hot
-    loop as a Pallas TPU kernel (VPU vector arithmetic with the mapping
-    axis on the lanes).  On hosts without a TPU the kernel runs under
+  * ``jnp``    — `core.batch_eval.evaluate_batch_multi` on one group, the
+    vectorized oracle (validated against the scalar evaluator and the loop
+    simulator);
+  * ``pallas`` — `kernels.mapspace_eval.mapspace_eval_multi` on one group,
+    the paper's mapping-scoring hot loop as a Pallas TPU kernel (VPU
+    vector arithmetic with the mapping axis on the lanes).  On hosts
+    without a TPU the kernel runs under
     ``pl.pallas_call(..., interpret=True)`` so the code path is always
     testable; on TPU it compiles for the VPU.
 
@@ -15,8 +17,7 @@ Batches are array-native end to end: a `core.mapspace_array.PackedMapspace`
 is consumed without any conversion, and a legacy `Sequence[Mapping]` is
 packed exactly once here — the packed arrays are shared by the kernel
 scorer, the jnp fallback, and the closed-form `validity_mask`, so no path
-re-packs (the seed packed twice: once in `ops.mapspace_eval`, once in
-`validity_mask`).
+re-packs.
 
 The kernel's storage chains are the full memory hierarchy, so only
 *no-bypass* mappings are eligible.  Eligibility is detected per mapping:
@@ -29,8 +30,8 @@ is attached (the kernel then beats per-mapping jnp dispatch) and to
 
 The kernel emits (cycles, energy) only; validity (fanout + buffer-capacity
 checks) is closed-form per mapping and computed here with the same
-formulas `evaluate_batch` uses, so both backends agree on the valid set
-exactly.
+formulas `evaluate_batch_multi` uses, so both backends agree on the valid
+set exactly.
 """
 from __future__ import annotations
 
@@ -97,7 +98,7 @@ def eligibility_mask(mappings) -> np.ndarray:
 def validity_mask_arrays(st: HwStatic, factors: np.ndarray,
                          store: np.ndarray) -> np.ndarray:
     """Fanout + buffer-capacity validity over packed arrays,
-    formula-identical to the checks in `evaluate_batch` (the pallas
+    formula-identical to the checks in `evaluate_batch_multi` (the pallas
     kernel does not emit validity)."""
     f = np.asarray(factors, np.float64)
     store = np.asarray(store)
@@ -140,11 +141,11 @@ def _as_arrays(mappings):
 def _pallas_scores_arrays(st: HwStatic, factors, rank, goal: str,
                           block: int, interpret: Optional[bool]
                           ) -> np.ndarray:
-    from ..kernels.mapspace_eval.ops import mapspace_eval_arrays
+    from ..kernels.mapspace_eval.ops import mapspace_eval_multi
     if interpret is None:
         interpret = default_interpret()
-    cycles, energy = mapspace_eval_arrays(st, factors, rank, block=block,
-                                          interpret=interpret)
+    cycles, energy = mapspace_eval_multi([(st, factors, rank)], block=block,
+                                         interpret=interpret)
     if goal == "latency":
         return np.asarray(cycles, np.float64)
     if goal == "energy":

@@ -11,10 +11,14 @@ packed into integer tensors
 
 and the whole evaluator (tile extents, buffer validity, delivery counts with
 halo credit, psum read-modify-write, NoC classification, cycles, energy,
-EDP) is closed-form batched arithmetic.  Semantics match
-`evaluator.evaluate_mapping` exactly — asserted by tests/test_batch_eval.py.
+EDP) is closed-form batched arithmetic in one function,
+`evaluate_batch_multi`, which takes each row's hardware/workload constants
+as arrays.  One mapspace scores as a one-group call (`batch_scores_arrays`);
+the fused search packs many mapspaces sharing a `BatchSig` into one.
+Semantics match `evaluator.evaluate_mapping` — asserted by
+tests/test_property_oracle.py.
 
-The per-mapping scoring loop is also available as a Pallas TPU kernel
+The no-bypass part of the model is also a Pallas TPU kernel
 (`repro.kernels.mapspace_eval`) with this module as its oracle; callers
 pick an engine through `core.backend.score_mapspace` (backend dispatch
 with automatic no-bypass eligibility gating).
@@ -24,7 +28,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
-from typing import List, Optional, Sequence, Tuple
+from typing import List, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -127,38 +131,6 @@ def pack(mappings: Sequence[Mapping]):
     return factors, rank, store
 
 
-# ---------------------------------------------------------------------------
-def _tensor_tile_words(st: HwStatic, tile):
-    """tile: [..., 7] float -> dict tensor -> [...] words."""
-    n, m, c, r, s, e, f = (tile[..., i] for i in range(7))
-    u, v = st.stride
-    dr, ds = st.dilation
-    p = (e - 1) * u + (r - 1) * dr + 1
-    q = (f - 1) * v + (s - 1) * ds + 1
-    return {
-        "input": n * c * p * q,
-        "weight": (r * s * c * m) if st.has_weight else jnp.zeros_like(n),
-        "output": n * e * f * (c if st.depthwise else m),
-    }
-
-
-def _fresh_input_words(st: HwStatic, tile, slide_dim):
-    """Fresh input words for one slide step along slide_dim [..., int]."""
-    n, m, c, r, s, e, f = (tile[..., i] for i in range(7))
-    u, v = st.stride
-    dr, ds = st.dilation
-    p = (e - 1) * u + (r - 1) * dr + 1
-    q = (f - 1) * v + (s - 1) * ds + 1
-    fr_e = n * c * jnp.minimum(p, e * u) * q
-    fr_f = n * c * p * jnp.minimum(q, f * v)
-    fr_r = n * c * jnp.minimum(p, r * dr) * q
-    fr_s = n * c * p * jnp.minimum(q, s * ds)
-    out = jnp.where(slide_dim == E_, fr_e,
-                    jnp.where(slide_dim == F_, fr_f,
-                              jnp.where(slide_dim == R_, fr_r, fr_s)))
-    return out
-
-
 RELEVANT = {
     "input": np.array([1, 0, 1, 1, 1, 1, 1], bool),
     "weight": np.array([0, 1, 1, 1, 1, 0, 0], bool),
@@ -172,7 +144,7 @@ GOAL_KEY = {"latency": "cycles", "energy": "energy_pj", "edp": "edp"}
 
 def tile_words_np(st: HwStatic, tile):
     """tile: [..., 7] float -> [..., 3] words in TENSORS order.  Numpy
-    twin of `_tensor_tile_words`, shared by the kernel packer
+    twin of `_tile_words_b`, shared by the kernel packer
     (kernels/mapspace_eval/ops.py) and `core.backend.validity_mask` so
     the halo/depthwise/has-weight formulas exist exactly twice (jnp +
     np), not once per consumer."""
@@ -184,236 +156,6 @@ def tile_words_np(st: HwStatic, tile):
     w = (r * s * c * m) if st.has_weight else np.zeros_like(n)
     o = n * e * f * (c if st.depthwise else m)
     return np.stack([n * c * p * q, w, o], axis=-1)
-
-
-@functools.partial(jax.jit, static_argnums=0)
-def evaluate_batch(st: HwStatic, factors, rank, store):
-    """-> dict of [B] arrays: cycles, dynamic_pj, static_pj, energy_pj, edp,
-    valid, pes_used."""
-    B, L, _ = factors.shape
-    f32 = factors.astype(jnp.float64 if jax.config.jax_enable_x64
-                         else jnp.float32)
-    mem = list(st.mem_idx)
-    Lm = len(mem)
-
-    # ---- tiles: tile_at[:, l] = prod_{l' >= l} factors -------------------
-    rev = jnp.flip(f32, axis=1)
-    tile_at = jnp.flip(jnp.cumprod(rev, axis=1), axis=1)       # [B, L, 7]
-    tile_at = jnp.concatenate([tile_at, jnp.ones((B, 1, 7), f32.dtype)],
-                              axis=1)                          # [B, L+1, 7]
-
-    # ---- flattened temporal loop slots -----------------------------------
-    # slot order: (memory level asc, rank within level asc)
-    n_slots = Lm * 7
-    slot_bound = jnp.ones((B, n_slots), f32.dtype)
-    slot_dim = jnp.zeros((B, n_slots), jnp.int32)
-    for j, li in enumerate(mem):
-        pos = rank[:, li, :]                                   # [B, 7]
-        base = j * 7
-        idx = base + pos                                       # [B, 7]
-        slot_bound = jax.vmap(lambda sb, ix, fv: sb.at[ix].set(fv))(
-            slot_bound, idx, f32[:, li, :])
-        slot_dim = jax.vmap(lambda sd, ix: sd.at[ix].set(
-            jnp.arange(7, dtype=jnp.int32)))(slot_dim, idx)
-    active = slot_bound > 1.0                                  # [B, n_slots]
-    cum = jnp.cumprod(slot_bound, axis=1)                      # [B, n_slots]
-
-    rel_t = {t: jnp.asarray(RELEVANT[t]) for t in TENSORS}
-    if st.depthwise:
-        rel_t["output"] = jnp.asarray(
-            np.array([1, 1, 1, 0, 0, 1, 1], bool))
-    sliding = jnp.asarray(SLIDING)
-
-    rout = list(st.rout_idx)
-    rout_prod = [jnp.prod(f32[:, r, :], axis=1) for r in rout]   # [B] each
-
-    def inst_before(tiling_idx_arr):
-        """Used instances outer than (data-dependent) tiling index [B]."""
-        inst = jnp.ones((B,), f32.dtype)
-        for ri, r in enumerate(rout):
-            inst = inst * jnp.where(tiling_idx_arr > r, rout_prod[ri], 1.0)
-        return inst
-
-    def spatial_between(parent_tiling, child_tiling_static):
-        """Per-dim routing factors with parent < r < child. [B, 7]."""
-        S = jnp.ones((B, 7), f32.dtype)
-        for ri, r in enumerate(rout):
-            if r < child_tiling_static:
-                m = (parent_tiling < r)[:, None]
-                S = S * jnp.where(m, f32[:, r, :], 1.0)
-        return S
-
-    def scan_pair(child_j, tensor, parent_tiling):
-        """Traffic for chain pair into child at mem position child_j
-        (child_j == Lm means COMPUTE).  Returns dict of [B] arrays."""
-        if child_j == Lm:
-            per_inst = jnp.ones((B, 7), f32.dtype)
-            child_tiling = st.n_levels
-            n_vis = n_slots
-        else:
-            per_inst = tile_at[:, mem[child_j]]
-            child_tiling = mem[child_j]
-            n_vis = child_j * 7
-        S = spatial_between(parent_tiling, child_tiling)
-        union = per_inst * S
-        pw = _tensor_tile_words(st, per_inst)[tensor]
-        uw = _tensor_tile_words(st, union)[tensor]
-        i_a = inst_before(parent_tiling)
-        i_b = inst_before(jnp.full((B,), child_tiling))
-        zero = jnp.zeros((B,), f32.dtype)
-        if n_vis == 0:
-            V = jnp.ones((B,), f32.dtype)
-            D = V
-            union_words = uw
-            has = jnp.zeros((B,), bool)
-        else:
-            rel = rel_t[tensor][slot_dim[:, :n_vis]] & active[:, :n_vis]
-            pos = jnp.arange(1, n_vis + 1)
-            k1 = jnp.max(jnp.where(rel, pos, 0), axis=1)       # 1-based
-            has = k1 > 0
-            kidx = jnp.maximum(k1 - 1, 0)
-            P_k = jnp.take_along_axis(cum[:, :n_vis], kidx[:, None],
-                                      axis=1)[:, 0]
-            b_k = jnp.take_along_axis(slot_bound[:, :n_vis], kidx[:, None],
-                                      axis=1)[:, 0]
-            d_k = jnp.take_along_axis(slot_dim[:, :n_vis], kidx[:, None],
-                                      axis=1)[:, 0]
-            outer = P_k / b_k
-            V = jnp.where(has, P_k, 1.0)
-            relb = rel & (pos[None, :] <= k1[:, None])
-            D = jnp.prod(jnp.where(relb, slot_bound[:, :n_vis], 1.0), axis=1)
-            D = jnp.where(has, D, 1.0)
-            union_words = V * uw
-            if tensor == "input" and child_j != Lm:
-                fresh = _fresh_input_words(st, union, d_k)
-                slid = outer * (uw + (b_k - 1) * fresh)
-                union_words = jnp.where(has & sliding[d_k], slid,
-                                        union_words)
-        if tensor == "output":
-            return {"parent_read": i_a * (V - D) * uw,
-                    "parent_write": i_a * V * uw,
-                    "child_read": zero if child_j == Lm else i_b * V * pw,
-                    "child_write": zero if child_j == Lm
-                    else i_b * (V - D) * pw,
-                    "noc": i_b * (2 * V - D) * pw}
-        return {"parent_read": i_a * union_words,
-                "parent_write": zero,
-                "child_read": zero,
-                "child_write": zero if child_j == Lm else i_b * V * pw,
-                "noc": i_a * union_words}
-
-    # ---- chain pairs: reads/writes per memory level ----------------------
-    reads = [jnp.zeros((B,), f32.dtype) for _ in range(Lm)]
-    writes = [jnp.zeros((B,), f32.dtype) for _ in range(Lm)]
-    raw = [jnp.zeros((B,), f32.dtype) for _ in range(Lm)]
-    # crossing words per routing level per class
-    n_r = len(st.rout_idx)
-    uni = jnp.zeros((B,), f32.dtype)
-    multi = jnp.zeros((B,), f32.dtype)
-    acc = jnp.zeros((B,), f32.dtype)
-    noc_raw = jnp.zeros((B,), f32.dtype)
-    spatial = [f32[:, r, :] for r in st.rout_idx]              # [B,7] each
-    m_w = [jnp.any(s[:, jnp.asarray([N_, E_, F_])] > 1, axis=1)
-           for s in spatial]
-    m_i = [spatial[i][:, M_] > 1 for i in range(n_r)]
-    a_o = [jnp.any(s[:, jnp.asarray([C_, R_, S_])] > 1, axis=1)
-           for s in spatial]
-
-    zf = {"input": 1.0 - st.in_zf,
-          "weight": 1.0 - (st.w_zf if st.has_weight else 0.0),
-          "output": 1.0}
-
-    tensors = ["input", "output"] + (["weight"] if st.has_weight else [])
-    for ti, tensor in enumerate(TENSORS):
-        if tensor not in tensors:
-            continue
-        st_flag = store[:, :, ti]                              # [B, Lm]
-        for child_j in list(range(1, Lm)) + [Lm]:
-            if child_j < Lm:
-                stores_child = st_flag[:, child_j]
-            else:
-                stores_child = jnp.ones((B,), bool)
-            # parent = largest storing mem position < child_j
-            cand = st_flag[:, :child_j]
-            ppos = jnp.max(jnp.where(cand,
-                                     jnp.arange(child_j)[None, :], 0),
-                           axis=1)                             # [B]
-            parent_tiling = jnp.asarray(mem)[ppos]
-            stats = scan_pair(child_j, tensor, parent_tiling)
-            zs_f = jnp.where(
-                (st.zs_boundary >= 0) & (parent_tiling >= st.zs_boundary)
-                & (tensor != "output"), zf[tensor], 1.0)
-            gate0 = stores_child.astype(f32.dtype)
-            gate = gate0 * zs_f
-            for j in range(Lm):
-                sel = (ppos == j).astype(f32.dtype)
-                reads[j] = reads[j] + sel * gate * stats["parent_read"]
-                writes[j] = writes[j] + sel * gate * stats["parent_write"]
-                raw[j] = raw[j] + sel * gate0 * (stats["parent_read"]
-                                                 + stats["parent_write"])
-            if child_j < Lm:
-                writes[child_j] = writes[child_j] \
-                    + gate * stats["child_write"]
-                reads[child_j] = reads[child_j] + gate * stats["child_read"]
-                raw[child_j] = raw[child_j] + gate0 * (
-                    stats["child_write"] + stats["child_read"])
-            # routing crossings: parent_tiling < r < child_tiling
-            child_tiling = (mem[child_j] if child_j < Lm else st.n_levels)
-            w = gate * stats["noc"]
-            w_raw = gate0 * stats["noc"]
-            for ri, r in enumerate(st.rout_idx):
-                crosses = (parent_tiling < r) & (r < child_tiling)
-                wc = jnp.where(crosses, w, 0.0)
-                noc_raw = noc_raw + jnp.where(crosses, w_raw, 0.0)
-                if tensor == "weight":
-                    uni = uni + jnp.where(m_w[ri], 0.0, wc)
-                    multi = multi + jnp.where(m_w[ri], wc, 0.0)
-                elif tensor == "input":
-                    uni = uni + jnp.where(m_i[ri], 0.0, wc)
-                    multi = multi + jnp.where(m_i[ri], wc, 0.0)
-                else:
-                    uni = uni + jnp.where(a_o[ri], 0.0, wc)
-                    acc = acc + jnp.where(a_o[ri], wc, 0.0)
-
-    # ---- cycles / energy ---------------------------------------------------
-    macs = float(math.prod(st.dims))
-    pes_used = jnp.prod(jnp.stack([jnp.prod(s, axis=1) for s in spatial],
-                                  axis=0), axis=0) if spatial else \
-        jnp.ones((B,), f32.dtype)
-    comp_cycles = macs / (jnp.maximum(pes_used, 1.0)
-                          * st.macs_per_pe * st.pipeline)
-    cycles = comp_cycles
-    dyn = jnp.full((B,), macs * zf["input"] * zf["weight"] * st.mac_e
-                   if st.zs_boundary >= 0 else macs * st.mac_e, f32.dtype)
-    leak_rate = st.pe_leak * st.num_pes
-    for j in range(Lm):
-        inst_j = inst_before(jnp.full((B,), mem[j]))
-        cycles = jnp.maximum(cycles, raw[j] / (st.bandwidths[j] * inst_j))
-        dyn = dyn + reads[j] * st.read_e[j] + writes[j] * st.write_e[j]
-        leak_rate = leak_rate + st.leak[j]
-    for ri in range(n_r):
-        cycles = jnp.maximum(cycles, noc_raw / st.noc_bw[ri])
-        dyn = dyn + (uni * st.uni_e[ri] + multi * st.multi_e[ri]
-                     + acc * st.acc_e[ri])
-    static = leak_rate * cycles
-    energy = dyn + static
-
-    # ---- validity ----------------------------------------------------------
-    valid = jnp.ones((B,), bool)
-    for ri, r in enumerate(st.rout_idx):
-        valid &= jnp.prod(f32[:, r, :], axis=1) <= st.fanout[ri]
-    for j, li in enumerate(mem):
-        if not math.isfinite(st.sizes[j]):
-            continue
-        tw = _tensor_tile_words(st, tile_at[:, li])
-        used = jnp.zeros((B,), f32.dtype)
-        for ti, t in enumerate(TENSORS):
-            used = used + jnp.where(store[:, j, ti], tw[t], 0.0)
-        valid &= used <= st.sizes[j]
-
-    return {"cycles": cycles, "dynamic_pj": dyn, "static_pj": static,
-            "energy_pj": energy, "edp": cycles * energy, "valid": valid,
-            "pes_used": pes_used}
 
 
 def _bucket(n: int) -> int:
@@ -481,20 +223,15 @@ def note_batch_dispatch(bucket_rows: int) -> None:
 
 
 # ---------------------------------------------------------------------------
-# Multi-architecture fused batches (repro.search.batch_frontier).
-#
-# `evaluate_batch` bakes every hardware constant into the jit closure via the
-# static HwStatic, so each (arch, workload) pair compiles and dispatches its
-# own program.  For cross-architecture DSE the numeric constants (capacities,
-# bandwidths, energies, workload bounds) become per-mapping *arrays* instead,
-# and only the structural shape of the evaluation — level layout, tensor set,
-# depthwise semantics — stays static.  Mapspaces of any two architectures
-# sharing a BatchSig then pack into a single device call.
+# The batched cost model.  Only the structure of an evaluation (`BatchSig`)
+# is static; every numeric constant is a per-row array (`params_of`), so rows
+# of any architectures and workloads sharing a BatchSig score in one call.
 # ---------------------------------------------------------------------------
 @dataclasses.dataclass(frozen=True)
 class BatchSig:
-    """Structural signature of an evaluation: everything `evaluate_batch`
-    uses for control flow / array shapes, nothing it uses as a number."""
+    """Structural signature of an evaluation: everything
+    `evaluate_batch_multi` uses for control flow / array shapes, nothing it
+    uses as a number."""
     n_levels: int
     mem_idx: Tuple[int, ...]
     rout_idx: Tuple[int, ...]
@@ -562,12 +299,10 @@ def _fresh_input_words_b(stride, dilation, tile, slide_dim):
 
 @functools.partial(jax.jit, static_argnums=0)
 def evaluate_batch_multi(sig: BatchSig, params, factors, rank, store):
-    """`evaluate_batch` with per-mapping hardware/workload constants.
-
-    Semantics match `evaluate_batch` row-for-row when every row carries the
-    same architecture (asserted by tests/test_search.py); rows may mix any
-    architectures/workloads that share `sig`.
-    """
+    """-> dict of [B] arrays: cycles, dynamic_pj, static_pj, energy_pj,
+    edp, valid, pes_used.  `params` holds each row's hardware/workload
+    constants (`params_of`); rows may mix any architectures/workloads that
+    share `sig`."""
     B, L, _ = factors.shape
     f32 = factors.astype(jnp.float64 if jax.config.jax_enable_x64
                          else jnp.float32)
@@ -786,8 +521,9 @@ def evaluate_batch_multi(sig: BatchSig, params, factors, rank, store):
 
 def batch_scores_arrays(st: HwStatic, factors, rank, store,
                         goal: str = "edp"):
-    """`batch_scores` on pre-packed arrays (numpy or jnp); pads the
-    mapping axis to a power-of-2 bucket and evaluates one jit call."""
+    """`batch_scores` on pre-packed arrays (numpy or jnp) of one mapspace:
+    pads the mapping axis to a power-of-2 bucket and scores it as a
+    one-group `evaluate_batch_multi` call."""
     factors = jnp.asarray(factors)
     rank = jnp.asarray(rank)
     store = jnp.asarray(store)
@@ -801,7 +537,8 @@ def batch_scores_arrays(st: HwStatic, factors, rank, store,
     # so device time is attributable even when no caller holds one open
     # (trimlint R-SYNC — some callers, e.g. batch_scores, are bare)
     with current_tracer().span("batch_eval.scores", rows=int(n)):
-        out = evaluate_batch(st, factors, rank, store)
+        out = evaluate_batch_multi(sig_of(st), params_of(st, n + pad),
+                                   factors, rank, store)
         key = {"latency": "cycles", "energy": "energy_pj",
                "edp": "edp"}[goal]
         return np.asarray(out[key][:n]), np.asarray(out["valid"][:n])

@@ -47,8 +47,8 @@ class PackedMapspace:
 
     The array triplet (factors, rank, store) is exactly what
     `batch_eval.pack` would produce for the equivalent `Mapping` list, so
-    every array consumer (`evaluate_batch`, `evaluate_batch_multi`, the
-    Pallas kernels, `validity_mask`) takes it unchanged — zero re-packing
+    every array consumer (`evaluate_batch_multi`, the Pallas kernel,
+    `validity_mask`) takes it unchanged — zero re-packing
     anywhere downstream.
     """
     workload: Workload

@@ -26,16 +26,13 @@ tensor being feature k of every mapping:
   noc_e [L1*3,B]       NoC pJ/word per pair per tensor (0 if no crossing)
   noc_m [L1,B]         1 if the pair crosses a routing level
 
-The scoring math lives once, in `_score_body`, parameterized by how the
-hardware/workload constants are sourced: the single-arch kernel bakes
-them as static Python floats (functools.partial), the multi-arch variant
-reads them from per-row arrays (zsf [L1*3, B], mem_par [Lm*3, B],
-hw_row [4, B]) so rows of any architectures sharing a structural
-BatchSig fuse into one call — the `evaluate_batch_multi` contract.
-The multi-arch call is a module-level `jax.jit` keyed on the chain layout
-(`vis`, `n_mem`), `block` and the bucketed shapes, so each program is
-traced and lowered once per process; the single-arch kernel, whose static
-floats differ per design, is called eagerly.
+The hardware/workload constants are per-row arrays too (zsf [L1*3, B],
+mem_par [Lm*3, B], hw_row [4, B]), so rows of any architectures sharing a
+structural BatchSig fuse into one call — the `evaluate_batch_multi`
+contract; a single mapspace is a one-group call.  The call is a
+module-level `jax.jit` keyed on the chain layout (`vis`, `n_mem`),
+`block` and the bucketed shapes, so each program is traced and lowered
+once per process.
 """
 from __future__ import annotations
 
@@ -54,15 +51,20 @@ def _row(ref, k: int):
     return ref[k:k + 1, :]
 
 
-def _score_body(bounds_ref, cum_ref, rel_i_ref, rel_w_ref, rel_o_ref,
-                tw_u_ref, tw_p_ref, fresh_ref, ia_ref, ib_ref,
-                noc_e_ref, noc_m_ref, cycles_ref, energy_ref, *,
-                vis: Tuple[int, ...], n_mem: int,
-                zsf_of, mem_bw_of, e_read_of, e_write_of,
-                comp_cycles_of, dyn0_of, leak_of, noc_bw_of):
-    """The scoring pipeline, shared by both kernels.  The `*_of` getters
-    return either static Python floats (single-arch) or [1, Bm] row
-    vectors (multi-arch) — the math broadcasts identically."""
+def _score_kernel_multi(bounds_ref, cum_ref, rel_i_ref, rel_w_ref,
+                        rel_o_ref, tw_u_ref, tw_p_ref, fresh_ref, ia_ref,
+                        ib_ref, noc_e_ref, noc_m_ref, zsf_ref, mem_par_ref,
+                        hw_row_ref, cycles_ref, energy_ref, *,
+                        vis: Tuple[int, ...], n_mem: int):
+    """The scoring pipeline over one [*, Bm] block of mappings, with
+    per-row hardware/workload constants (same contract as
+    `core.batch_eval.evaluate_batch_multi`):
+
+      zsf     [L1*3, Bm]  zero-skip factor per chain pair per tensor
+      mem_par [Lm*3, Bm]  (bandwidth, read_e, write_e) per memory level
+      hw_row  [4, Bm]     (comp_scale, eff_mac_pj, leak_rate, noc_bw)
+                          with comp_scale = macs / (macs_per_pe * pipe)
+    """
     bounds = bounds_ref[...]                    # [S, Bm]
     cum = cum_ref[...]
     rel_refs = (rel_i_ref, rel_w_ref, rel_o_ref)
@@ -78,7 +80,7 @@ def _score_body(bounds_ref, cum_ref, rel_i_ref, rel_w_ref, rel_o_ref,
     writes = [zero] * n_mem
     raw = [zero] * n_mem
     noc_words = zero
-    dyn = dyn0_of(bm)
+    dyn = _row(hw_row_ref, 1)
 
     L1 = len(vis)
     for j in range(L1):
@@ -101,7 +103,7 @@ def _score_body(bounds_ref, cum_ref, rel_i_ref, rel_w_ref, rel_o_ref,
                                          keepdims=True), 1.0)
             vv = p_k
             outer = p_k / b_k
-            zsf = zsf_of(j, t)
+            zsf = _row(zsf_ref, j * 3 + t)
             ne = _row(noc_e_ref, j * 3 + t)
             if t == 2:                                        # output
                 # product of the relevant active bounds at or inside k1,
@@ -147,80 +149,30 @@ def _score_body(bounds_ref, cum_ref, rel_i_ref, rel_w_ref, rel_o_ref,
                 dyn += nw * zsf * ne
 
     pes = _row(ib_ref, L1 - 1)                  # instances at compute leaf
-    cycles = comp_cycles_of(pes)
+    cycles = _row(hw_row_ref, 0) / jnp.maximum(pes, 1.0)
     for m in range(n_mem):
         inst_m = _row(ia_ref, m)                # parent of pair m = level m
-        cycles = jnp.maximum(cycles, raw[m] / (mem_bw_of(m) * inst_m))
-        dyn += reads[m] * e_read_of(m) + writes[m] * e_write_of(m)
-    cycles = jnp.maximum(cycles, noc_words / noc_bw_of())
-    energy = dyn + leak_of() * cycles
+        cycles = jnp.maximum(cycles,
+                             raw[m] / (_row(mem_par_ref, m * 3) * inst_m))
+        dyn += (reads[m] * _row(mem_par_ref, m * 3 + 1)
+                + writes[m] * _row(mem_par_ref, m * 3 + 2))
+    cycles = jnp.maximum(cycles, noc_words / _row(hw_row_ref, 3))
+    energy = dyn + _row(hw_row_ref, 2) * cycles
     cycles_ref[...] = cycles
     energy_ref[...] = energy
 
 
-def _score_kernel(bounds_ref, cum_ref, rel_i_ref, rel_w_ref, rel_o_ref,
-                  tw_u_ref, tw_p_ref, fresh_ref, ia_ref, ib_ref,
-                  noc_e_ref, noc_m_ref,
-                  cycles_ref, energy_ref, *,
-                  vis: Tuple[int, ...],
-                  mem_bw: Tuple[float, ...],
-                  e_read: Tuple[float, ...], e_write: Tuple[float, ...],
-                  zs_parent: Tuple[int, ...],
-                  zf: Tuple[float, float, float],
-                  macs: float, macs_per_pe: float, pipeline: float,
-                  mac_energy: float, eff_macs: float, leak_rate: float,
-                  noc_bw: float, n_mem: int):
-    """Single-arch kernel: hardware constants baked as static floats."""
-    _score_body(
-        bounds_ref, cum_ref, rel_i_ref, rel_w_ref, rel_o_ref,
-        tw_u_ref, tw_p_ref, fresh_ref, ia_ref, ib_ref,
-        noc_e_ref, noc_m_ref, cycles_ref, energy_ref,
-        vis=vis, n_mem=n_mem,
-        zsf_of=lambda j, t: zf[t] if zs_parent[j] else 1.0,
-        mem_bw_of=lambda m: mem_bw[m],
-        e_read_of=lambda m: e_read[m],
-        e_write_of=lambda m: e_write[m],
-        comp_cycles_of=lambda pes: macs / (jnp.maximum(pes, 1.0)
-                                           * macs_per_pe * pipeline),
-        dyn0_of=lambda bm: jnp.full((1, bm), eff_macs * mac_energy,
-                                    jnp.float32),
-        leak_of=lambda: leak_rate,
-        noc_bw_of=lambda: noc_bw)
-
-
-def _score_kernel_multi(bounds_ref, cum_ref, rel_i_ref, rel_w_ref,
-                        rel_o_ref, tw_u_ref, tw_p_ref, fresh_ref, ia_ref,
-                        ib_ref, noc_e_ref, noc_m_ref, zsf_ref, mem_par_ref,
-                        hw_row_ref, cycles_ref, energy_ref, *,
-                        vis: Tuple[int, ...], n_mem: int):
-    """Multi-architecture kernel: the same scoring body with per-row
-    hardware/workload constants (same contract as
-    `core.batch_eval.evaluate_batch_multi`):
-
-      zsf     [L1*3, Bm]  zero-skip factor per chain pair per tensor
-      mem_par [Lm*3, Bm]  (bandwidth, read_e, write_e) per memory level
-      hw_row  [4, Bm]     (comp_scale, eff_mac_pj, leak_rate, noc_bw)
-                          with comp_scale = macs / (macs_per_pe * pipe)
-    """
-    _score_body(
-        bounds_ref, cum_ref, rel_i_ref, rel_w_ref, rel_o_ref,
-        tw_u_ref, tw_p_ref, fresh_ref, ia_ref, ib_ref,
-        noc_e_ref, noc_m_ref, cycles_ref, energy_ref,
-        vis=vis, n_mem=n_mem,
-        zsf_of=lambda j, t: _row(zsf_ref, j * 3 + t),
-        mem_bw_of=lambda m: _row(mem_par_ref, m * 3),
-        e_read_of=lambda m: _row(mem_par_ref, m * 3 + 1),
-        e_write_of=lambda m: _row(mem_par_ref, m * 3 + 2),
-        comp_cycles_of=lambda pes: _row(hw_row_ref, 0)
-        / jnp.maximum(pes, 1.0),
-        dyn0_of=lambda bm: _row(hw_row_ref, 1),
-        leak_of=lambda: _row(hw_row_ref, 2),
-        noc_bw_of=lambda: _row(hw_row_ref, 3))
-
-
-def _lane_call(kern, arrays, block: int, interpret: bool, name: str):
-    """pallas_call over the lane (mapping) axis: every input is [..., B]
-    and is tiled [..., block]; outputs are (cycles, energy) as [1, B]."""
+@functools.partial(jax.jit,
+                   static_argnames=("vis", "n_mem", "block", "interpret"))
+def _multi_call(*arrays, vis: Tuple[int, ...], n_mem: int, block: int,
+                interpret: bool):
+    """The Pallas call over the lane (mapping) axis: every input is
+    [..., B] and is tiled [..., block]; outputs are (cycles, energy) as
+    [1, B].  Compiled once per process for each chain layout (`vis`,
+    `n_mem`), `block`, `interpret` and array shapes.  This body runs only
+    while JAX traces it, so `kernel.traces` counts the programs built, not
+    the calls."""
+    current_tracer().metrics.counter("kernel.traces").inc()
     b = arrays[0].shape[-1]
     assert b % block == 0, (b, block)
 
@@ -232,39 +184,14 @@ def _lane_call(kern, arrays, block: int, interpret: bool, name: str):
     out_spec = pl.BlockSpec((1, block), lambda i: (0, i))
     out_shape = jax.ShapeDtypeStruct((1, b), jnp.float32)
     return pl.pallas_call(
-        kern,
+        functools.partial(_score_kernel_multi, vis=vis, n_mem=n_mem),
         grid=(b // block,),
         in_specs=[spec(a) for a in arrays],
         out_specs=[out_spec, out_spec],
         out_shape=[out_shape, out_shape],
         interpret=interpret,
-        name=name,
+        name="mapspace_eval_multi",
     )(*arrays)
-
-
-def mapspace_eval_fwd(bounds, cum, rel_i, rel_w, rel_o, tw_u, tw_p, fresh,
-                      ia, ib, noc_e, noc_m, *, static: dict,
-                      block: int = 256, interpret: bool = False):
-    """All array args: trailing mapping axis B (multiple of `block`).
-    Returns (cycles [1, B], energy [1, B])."""
-    kern = functools.partial(_score_kernel, **static)
-    return _lane_call(kern, [bounds, cum, rel_i, rel_w, rel_o, tw_u, tw_p,
-                             fresh, ia, ib, noc_e, noc_m],
-                      block, interpret, "mapspace_eval")
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("vis", "n_mem", "block", "interpret"))
-def _multi_call(*arrays, vis: Tuple[int, ...], n_mem: int, block: int,
-                interpret: bool):
-    """The multi-architecture Pallas call, compiled once per process for
-    each chain layout (`vis`, `n_mem`), `block`, `interpret` and array
-    shapes.  This body runs only while JAX traces it, so `kernel.traces`
-    counts the programs built, not the calls."""
-    current_tracer().metrics.counter("kernel.traces").inc()
-    kern = functools.partial(_score_kernel_multi, vis=vis, n_mem=n_mem)
-    return _lane_call(kern, list(arrays), block, interpret,
-                      "mapspace_eval_multi")
 
 
 def mapspace_eval_multi_fwd(bounds, cum, rel_i, rel_w, rel_o, tw_u, tw_p,

@@ -1,15 +1,12 @@
-"""Jit'd wrappers: packed mapspace arrays -> kernel tensors -> scores.
+"""Host wrappers: packed mapspace arrays -> kernel tensors -> scores.
 
 Precomputes the per-mapping tensors described in kernel.py (cheap numpy)
-from packed `(factors, rank)` arrays.  Three entry points:
-
-  * `mapspace_eval(mappings, ...)`        — legacy object API (packs once);
-  * `mapspace_eval_arrays(st, f, r, ...)` — pre-packed arrays, one
-    hardware/workload pair baked statically (single-arch kernel);
-  * `mapspace_eval_multi(groups, ...)`    — cross-architecture batches:
-    rows from several `(HwStatic, factors, rank)` groups sharing one
-    `BatchSig` fuse into ONE kernel call with per-row hardware constants
-    (same contract as `core.batch_eval.evaluate_batch_multi`).
+from packed `(factors, rank)` arrays.  One entry point,
+`mapspace_eval_multi(groups, ...)`: rows from one or more
+`(HwStatic, factors, rank)` groups sharing one `BatchSig` fuse into ONE
+kernel call with per-row hardware constants (same contract as
+`core.batch_eval.evaluate_batch_multi`); a single mapspace is a one-group
+call.
 
 Only no-bypass mappings are accepted (the kernel's storage chains are the
 full memory hierarchy); the general path is core.batch_eval, and
@@ -19,18 +16,16 @@ eligibility gating.
 from __future__ import annotations
 
 import math
-from typing import List, Sequence, Tuple
+from typing import List, Tuple
 
 import jax.numpy as jnp
 import numpy as np
 
 from ...core.batch_eval import (RELEVANT, SLIDING, HwStatic, bucket,
-                                make_static, pack, sig_of,
-                                tile_words_np as _tile_words_np)
-from ...core.mapping import Mapping
+                                sig_of, tile_words_np as _tile_words_np)
 from ...core.workload import N_, M_, C_, R_, S_, E_, F_
 from ...obs import current_tracer
-from .kernel import mapspace_eval_fwd, mapspace_eval_multi_fwd
+from .kernel import mapspace_eval_multi_fwd
 
 
 def _fresh_np(st: HwStatic, tile, d):
@@ -50,9 +45,7 @@ def _fresh_np(st: HwStatic, tile, d):
 
 def _mapping_rows(st: HwStatic, factors: np.ndarray, rank: np.ndarray):
     """The twelve per-mapping kernel tensors (numpy) for one hardware/
-    workload pair.  Shared by the single-arch packer (which bakes the
-    hardware numerics statically) and the multi-arch packer (which turns
-    them into per-row arrays)."""
+    workload pair, with each chain pair's zero-skip flag."""
     factors = np.asarray(factors, np.float32)
     rank = np.asarray(rank)
     B, L, _ = factors.shape
@@ -133,12 +126,12 @@ def _mapping_rows(st: HwStatic, factors: np.ndarray, rank: np.ndarray):
 
     arrays = [slot_bound, cum, rel_i, rel_w, rel_o, tw_u, tw_p, fresh,
               ia, ib, noc_e, noc_m]
-    return arrays, tuple(zs_parent), Lm, L1, S
+    return arrays, tuple(zs_parent), Lm, L1
 
 
 def _hw_numerics(st: HwStatic):
-    """The scalar hardware/workload numerics the single-arch kernel bakes
-    statically (and the multi-arch kernel reads as per-row arrays)."""
+    """The scalar hardware/workload numerics of one (arch, workload)
+    pair, from which `_multi_rows` builds the per-row kernel constants."""
     macs = float(math.prod(st.dims))
     nz = (1.0 - st.in_zf) * (1.0 - (st.w_zf if st.has_weight else 0.0))
     eff = macs * nz if st.zs_boundary >= 0 else macs
@@ -174,56 +167,6 @@ def _to_lanes(arrays, block: int):
     return out, block
 
 
-def pack_for_kernel_arrays(st: HwStatic, factors, rank, block: int = 256):
-    """Pre-packed arrays -> (kernel arrays, static dict, n, block) for the
-    single-arch kernel."""
-    arrays, zs_parent, Lm, L1, _ = _mapping_rows(st, factors, rank)
-    B = arrays[0].shape[0]
-    hw = _hw_numerics(st)
-    static = dict(
-        vis=tuple((jj + 1) * 7 for jj in range(L1)),
-        mem_bw=hw["mem_bw"], e_read=hw["e_read"], e_write=hw["e_write"],
-        zs_parent=zs_parent, zf=hw["zf"],
-        macs=hw["macs"], macs_per_pe=hw["macs_per_pe"],
-        pipeline=hw["pipeline"], mac_energy=hw["mac_energy"],
-        eff_macs=hw["eff_macs"], leak_rate=hw["leak_rate"],
-        noc_bw=hw["noc_bw"], n_mem=Lm)
-    arrays, block = _to_lanes(arrays, block)
-    return arrays, static, B, block
-
-
-def pack_for_kernel(mappings: Sequence[Mapping], block: int = 256):
-    """Legacy object API: packs the mappings once, then defers to
-    `pack_for_kernel_arrays`."""
-    for m in mappings:
-        assert all(not b for b in m.bypass), "kernel path is no-bypass only"
-    st = make_static(mappings[0].hardware, mappings[0].workload)
-    factors, rank, _ = pack(mappings)
-    return pack_for_kernel_arrays(st, factors, rank, block)
-
-
-def mapspace_eval_arrays(st: HwStatic, factors, rank, *, block: int = 256,
-                         interpret: bool = False):
-    """-> (cycles [n], energy [n]) float32 arrays from packed arrays."""
-    arrays, static, n, block = pack_for_kernel_arrays(st, factors, rank,
-                                                      block)
-    cycles, energy = mapspace_eval_fwd(*arrays, static=static, block=block,
-                                       interpret=interpret)
-    return np.asarray(cycles[0, :n]), np.asarray(energy[0, :n])
-
-
-def mapspace_eval(mappings: Sequence[Mapping], *, block: int = 256,
-                  interpret: bool = False):
-    """-> (cycles [n], energy [n]) float32 arrays (legacy object API)."""
-    arrays, static, n, block = pack_for_kernel(mappings, block)
-    cycles, energy = mapspace_eval_fwd(*arrays, static=static, block=block,
-                                       interpret=interpret)
-    return np.asarray(cycles[0, :n]), np.asarray(energy[0, :n])
-
-
-# ---------------------------------------------------------------------------
-# multi-architecture fused kernel batches
-# ---------------------------------------------------------------------------
 def _multi_rows(groups: List[Tuple[HwStatic, np.ndarray, np.ndarray]]):
     """Rows of several single-(arch, workload) groups -> the fused
     row-major arrays [B, ...] with per-row hardware constants, the
@@ -232,7 +175,7 @@ def _multi_rows(groups: List[Tuple[HwStatic, np.ndarray, np.ndarray]]):
     per_group = []
     for st, factors, rank in groups:
         assert sig_of(st) == sig0, "kernel groups must share a BatchSig"
-        arrays, zs_parent, Lm, L1, _ = _mapping_rows(st, factors, rank)
+        arrays, zs_parent, Lm, L1 = _mapping_rows(st, factors, rank)
         B = arrays[0].shape[0]
         hw = _hw_numerics(st)
         zsf = np.ones((B, L1, 3), np.float32)
@@ -273,6 +216,12 @@ def pack_for_kernel_multi(groups: List[Tuple[HwStatic, np.ndarray,
     fused, static, B = _multi_rows(groups)
     fused, block = _to_lanes(fused, block)
     return fused, static, B, block
+
+
+def pack_for_kernel_arrays(st: HwStatic, factors, rank, block: int = 256):
+    """`pack_for_kernel_multi` of one group.  Kept only because the
+    benchmark's own tests (tests/bench/test_bench_work.py) import it."""
+    return pack_for_kernel_multi([(st, factors, rank)], block)
 
 
 def mapspace_eval_multi(groups: List[Tuple[HwStatic, np.ndarray,

@@ -1,14 +1,14 @@
-"""Pure-jnp oracle for the mapspace-eval kernel: core.batch_eval restricted
-to no-bypass mappings (the kernel's semantics are defined as equal to
-this — and batch_eval itself is validated against the scalar evaluator and
-the brute-force loop simulator)."""
+"""Pure-jnp oracle for the mapspace-eval kernel: the one jnp cost model,
+`core.batch_eval`, restricted to no-bypass mappings (the kernel's semantics
+are defined as equal to this — and batch_eval itself is validated against
+the scalar evaluator and the brute-force loop simulator)."""
 from __future__ import annotations
 
 from typing import Sequence
 
 import numpy as np
 
-from ...core.batch_eval import evaluate_batch, make_static, pack
+from ...core.batch_eval import batch_scores_arrays, make_static, pack
 from ...core.mapping import Mapping
 
 
@@ -16,5 +16,6 @@ def mapspace_eval_ref(mappings: Sequence[Mapping]):
     """-> (cycles [n], energy [n]) float64/float32 arrays."""
     st = make_static(mappings[0].hardware, mappings[0].workload)
     factors, rank, store = pack(mappings)
-    out = evaluate_batch(st, factors, rank, store)
-    return np.asarray(out["cycles"]), np.asarray(out["energy_pj"])
+    cycles, _ = batch_scores_arrays(st, factors, rank, store, "latency")
+    energy, _ = batch_scores_arrays(st, factors, rank, store, "energy")
+    return np.asarray(cycles), np.asarray(energy)

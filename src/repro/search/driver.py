@@ -632,7 +632,7 @@ def run_search(task: Union[TaskDescription, TaskWorkloads],
                  (vectorized construction/validation, winner-only
                  materialization, content-digest cache keys); False keeps
                  the legacy object pipeline (identical winners — asserted
-                 in tests and benchmarked in bench_mapspace_throughput)
+                 in tests)
     trace      : observability (`repro.obs`): None inherits the ambient
                  tracer (a no-op unless `obs.activate` scoped one), True
                  records into a fresh `Tracer` (returned as

@@ -104,23 +104,31 @@ def _mapspaces():
             yield tw.intra[wi].name, ms
 
 
+def _one_group(ms):
+    """`mapspace_eval_multi`'s one-group argument for a mapping list."""
+    from repro.core.batch_eval import make_static, pack
+    st = make_static(ms[0].hardware, ms[0].workload)
+    factors, rank, _ = pack(ms)
+    return [(st, factors, rank)]
+
+
 @pytest.mark.parametrize("name,ms", list(_mapspaces()),
                          ids=lambda x: x if isinstance(x, str) else "")
 def test_mapspace_eval_matches_batch_oracle(name, ms):
-    from repro.kernels.mapspace_eval.ops import mapspace_eval
+    from repro.kernels.mapspace_eval.ops import mapspace_eval_multi
     from repro.kernels.mapspace_eval.ref import mapspace_eval_ref
-    ck, ek = mapspace_eval(ms, block=64, interpret=True)
+    ck, ek = mapspace_eval_multi(_one_group(ms), block=64, interpret=True)
     cr, er = mapspace_eval_ref(ms)
     np.testing.assert_allclose(ck, cr, rtol=1e-5)
     np.testing.assert_allclose(ek, er, rtol=1e-4)
 
 
 def test_mapspace_eval_pads_to_block():
-    from repro.kernels.mapspace_eval.ops import mapspace_eval
+    from repro.kernels.mapspace_eval.ops import mapspace_eval_multi
     from repro.kernels.mapspace_eval.ref import mapspace_eval_ref
     name, ms = next(_mapspaces())
     ms = ms[:37]                      # not a block multiple
-    ck, ek = mapspace_eval(ms, block=32, interpret=True)
+    ck, ek = mapspace_eval_multi(_one_group(ms), block=32, interpret=True)
     cr, er = mapspace_eval_ref(ms)
     assert ck.shape == (37,)
     np.testing.assert_allclose(ck, cr, rtol=1e-5)
@@ -130,13 +138,7 @@ def test_mapspace_eval_pads_to_block():
 def _multi_groups():
     """(HwStatic, factors, rank) groups of the first two `_mapspaces`,
     with the mappings each group's rows came from."""
-    from repro.core.batch_eval import make_static, pack
-    out = []
-    for _, ms in list(_mapspaces())[:2]:
-        st = make_static(ms[0].hardware, ms[0].workload)
-        factors, rank, _ = pack(ms)
-        out.append(((st, factors, rank), ms))
-    return out
+    return [(_one_group(ms)[0], ms) for _, ms in list(_mapspaces())[:2]]
 
 
 @pytest.mark.parametrize("block", [64, 256])

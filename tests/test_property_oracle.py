@@ -16,7 +16,7 @@ pytest.importorskip(
 from hypothesis import given, settings, strategies as st
 
 from repro.core import (MapperConfig, Workload, build_mapspace,
-                        evaluate_mapping, make_spatial_arch)
+                        evaluate_mapping, make_fpga_arch, make_spatial_arch)
 from repro.core.evaluator import COMPUTE, analyze_activity
 from repro.core.simulator import simulate_activity
 
@@ -75,11 +75,34 @@ def test_pool_upper_bound(c, k, e, f, seed):
     _compare(wl, seed, exact=(k == 1))
 
 
+def _jnp_model(groups):
+    """Score mapping lists (one per design/workload pair, all sharing a
+    BatchSig) in ONE `evaluate_batch_multi` call -> (cycles, energy)."""
+    from repro.core.batch_eval import (evaluate_batch_multi, make_static,
+                                       pack, params_of, sig_of)
+    sts = [make_static(ms[0].hardware, ms[0].workload) for ms in groups]
+    assert len({sig_of(s) for s in sts}) == 1
+    packs = [pack(ms) for ms in groups]
+    per = [params_of(s, len(ms)) for s, ms in zip(sts, groups)]
+    params = {k: np.concatenate([p[k] for p in per]) for k in per[0]}
+    f_, r_, s_ = (np.concatenate(a) for a in zip(*packs))
+    out = evaluate_batch_multi(sig_of(sts[0]), params, f_, r_, s_)
+    return np.asarray(out["cycles"]), np.asarray(out["energy_pj"])
+
+
+def _assert_matches_scalar(groups):
+    ms = [m for g in groups for m in g]
+    scalar = np.array([[evaluate_mapping(m).cycles,
+                        evaluate_mapping(m).energy_pj] for m in ms])
+    cycles, energy = _jnp_model(groups)
+    np.testing.assert_allclose(cycles, scalar[:, 0], rtol=1e-5)
+    np.testing.assert_allclose(energy, scalar[:, 1], rtol=1e-4)
+
+
 @settings(max_examples=15, deadline=None)
 @given(n=dim, m=dim, c=dim, r=small, e=dim, zf=st.floats(0, 0.9),
        seed=st.integers(0, 5))
 def test_batch_eval_matches_scalar(n, m, c, r, e, zf, seed):
-    from repro.core.batch_eval import evaluate_batch, make_static, pack
     wl = Workload(dims=(n, m, c, r, 1, e, 1), input_zero_frac=zf)
     hw = make_spatial_arch(num_pes=4, rf_words=64, gbuf_words=1024,
                            bits=16, zero_skip=True)
@@ -87,15 +110,42 @@ def test_batch_eval_matches_scalar(n, m, c, r, e, zf, seed):
                                              seed=seed)).mappings[:40]
     if not ms:
         return
-    scalar = np.array([[evaluate_mapping(m).cycles,
-                        evaluate_mapping(m).energy_pj] for m in ms])
-    stt = make_static(hw, wl)
-    f_, r_, s_ = pack(ms)
-    out = evaluate_batch(stt, f_, r_, s_)
-    np.testing.assert_allclose(np.asarray(out["cycles"]), scalar[:, 0],
-                               rtol=1e-5)
-    np.testing.assert_allclose(np.asarray(out["energy_pj"]), scalar[:, 1],
-                               rtol=1e-4)
+    _assert_matches_scalar([ms])
+
+
+def _case_mapspaces(case):
+    """Mapping lists of a workload class the hypothesis test never draws:
+    strided/dilated 2-D convs under bypass, FC layers with weight zeros,
+    depthwise pooling, the FPGA level layout, and two designs sharing a
+    BatchSig in one call."""
+    zs_hw = make_spatial_arch(num_pes=16, rf_words=64, gbuf_words=4096,
+                              bits=16, zero_skip=True)
+    conv = Workload(dims=(2, 4, 3, 3, 3, 4, 4), stride=(2, 2),
+                    dilation=(1, 2), input_zero_frac=0.3)
+    fc = Workload(dims=(4, 8, 16, 1, 1, 1, 1), weight_zero_frac=0.25)
+    pool = Workload(dims=(2, 1, 4, 3, 3, 3, 3), stride=(2, 2),
+                    depthwise=True, kind="pool_max")
+    fpga = make_fpga_arch(name="fpga", num_pes=16, cache_kb=4.0)
+    other = make_spatial_arch(num_pes=64, rf_words=128, gbuf_words=8192,
+                              bits=16)
+    pairs = {"conv_bypass": [(conv, zs_hw)], "fc": [(fc, zs_hw)],
+             "depthwise_pool": [(pool, zs_hw)], "fpga_arch": [(conv, fpga)],
+             "mixed_group": [(conv, zs_hw), (fc, other)]}[case]
+    out = []
+    for i, (wl, hw) in enumerate(pairs):
+        cfg = MapperConfig(max_mappings=200, seed=i)
+        out.append(build_mapspace(wl, hw, cfg).mappings[:48])
+    assert all(out)
+    return out
+
+
+@pytest.mark.parametrize("case", ["conv_bypass", "fc", "depthwise_pool",
+                                  "fpga_arch", "mixed_group"])
+def test_batch_eval_matches_scalar_cases(case):
+    groups = _case_mapspaces(case)
+    if case == "conv_bypass":
+        assert any(any(m.bypass) for m in groups[0])
+    _assert_matches_scalar(groups)
 
 
 @settings(max_examples=20, deadline=None)
