@@ -144,10 +144,9 @@ GOAL_KEY = {"latency": "cycles", "energy": "energy_pj", "edp": "edp"}
 
 def tile_words_np(st: HwStatic, tile):
     """tile: [..., 7] float -> [..., 3] words in TENSORS order.  Numpy
-    twin of `_tile_words_b`, shared by the kernel packer
-    (kernels/mapspace_eval/ops.py) and `core.backend.validity_mask` so
-    the halo/depthwise/has-weight formulas exist exactly twice (jnp +
-    np), not once per consumer."""
+    twin of `_tile_words_b`, shared by `core.mapspace_array` and
+    `core.backend.validity_mask`; the kernel's jnp prologue
+    (kernels/mapspace_eval/kernel.py) multiplies in the same order."""
     n, m, c, r, s, e, f = (tile[..., i] for i in range(7))
     u, v = st.stride
     dr, ds = st.dilation
